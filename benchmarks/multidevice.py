@@ -33,7 +33,6 @@ sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import attend, AttentionConfig, DistrConfig
-from repro.utils.jax_compat import set_mesh
 from benchmarks.common import timeit
 
 B, H, N, D = 8, 8, {n}, 128
@@ -53,7 +52,7 @@ for ndev in {ndevs}:
     mesh = jax.sharding.Mesh(jax.devices()[:ndev], ("data",))
     sh = NamedSharding(mesh, P("data"))
     qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         t_f = timeit(jax.jit(flash), qs, ks, vs, warmup=1, iters=3)
         t_d = timeit(jax.jit(distr), qs, ks, vs, warmup=1, iters=3)
     out.append(dict(devices=ndev, flash_us=t_f, distr_us=t_d,
@@ -106,12 +105,15 @@ print("RINGJSON:" + json.dumps(out))
 """
 
 
-def _run_sub(script: str, marker: str, rows: list, label: str):
+def _run_sub(script: str, marker: str, label: str):
+    """Run one child on 8 simulated host devices.  The child is pinned to
+    the CPU, so it never contends with a parent that holds an accelerator;
+    a failed child raises."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                         text=True, timeout=1100)
+                         text=True, timeout=1100, env=env)
     if res.returncode != 0:
-        rows.append((f"{label}/FAILED", 0.0, res.stderr[-200:]))
-        return None
+        raise RuntimeError(f"{label} child failed:\n{res.stderr[-2000:]}")
     return json.loads(res.stdout.split(marker)[1])
 
 
@@ -126,16 +128,15 @@ def run(smoke: bool = False) -> list[tuple]:
             n=256 if smoke else 2048,
             ndevs=(1, 2) if smoke else (1, 2, 4, 8),
         ),
-        "JSON:", rows, "multidevice",
+        "JSON:", "multidevice",
     )
-    if records is not None:
-        if not smoke:
-            save_result("multidevice", records)
-        for r in records:
-            rows.append((
-                f"multidevice/devices={r['devices']}", r["distr_us"],
-                f"flash={r['flash_us']:.0f}us speedup={r['speedup']:.2f}x",
-            ))
+    if not smoke:
+        save_result("multidevice", records)
+    for r in records:
+        rows.append((
+            f"multidevice/devices={r['devices']}", r["distr_us"],
+            f"flash={r['flash_us']:.0f}us speedup={r['speedup']:.2f}x",
+        ))
 
     ring = _run_sub(
         textwrap.dedent(_RING_SCRIPT).format(
@@ -143,20 +144,19 @@ def run(smoke: bool = False) -> list[tuple]:
             n=256 if smoke else 1024,
             ndevs=(1, 2) if smoke else (1, 2, 4, 8),
         ),
-        "RINGJSON:", rows, "multidevice/ring",
+        "RINGJSON:", "multidevice/ring",
     )
-    if ring is not None:
-        if not smoke:
-            save_result("ring", ring)
-            with open(os.path.abspath(BENCH_RING_PATH), "w") as f:
-                json.dump(ring, f, indent=1)
-        for r in ring:
-            mode = "interpret" if r["interpret"] else "compiled"
-            rows.append((
-                f"multidevice/ring/devices={r['devices']}",
-                r["ring_flash_us"],
-                f"distr={r['ring_distr_us']:.0f}us "
-                f"single_flash={r['single_flash_us']:.0f}us "
-                f"hops={r['hops']} backend={r['backend']}:{mode}",
-            ))
+    if not smoke:
+        save_result("ring", ring)
+        with open(os.path.abspath(BENCH_RING_PATH), "w") as f:
+            json.dump(ring, f, indent=1)
+    for r in ring:
+        mode = "interpret" if r["interpret"] else "compiled"
+        rows.append((
+            f"multidevice/ring/devices={r['devices']}",
+            r["ring_flash_us"],
+            f"distr={r['ring_distr_us']:.0f}us "
+            f"single_flash={r['single_flash_us']:.0f}us "
+            f"hops={r['hops']} backend={r['backend']}:{mode}",
+        ))
     return rows

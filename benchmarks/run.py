@@ -87,6 +87,10 @@ def main() -> None:
     args = ap.parse_args()
     names = args.only or BENCHES
 
+    from repro.utils.compile_cache import use_compile_cache
+
+    print(f"[bench] compile cache: {use_compile_cache()}", file=sys.stderr)
+
     from repro.obs import (
         NULL_RECORDER, MetricsRegistry, TraceRecorder, set_recorder,
     )

@@ -13,7 +13,7 @@ from repro.models import lm
 from repro.serve.engine import ServeEngine
 
 cfg = get_config("qwen2.5-32b", reduced=True)
-params = lm.init_params(jax.random.PRNGKey(0), cfg)
+params = lm.init_serving_params(jax.random.PRNGKey(0), cfg)
 engine = ServeEngine(cfg, params, max_slots=4, max_len=128, temperature=0.7)
 
 rng = np.random.default_rng(0)

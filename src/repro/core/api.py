@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.distr_attention import DistrConfig, distr_attention
@@ -107,10 +108,8 @@ def _active_context_mesh(context_axis: str | None):
     paths apply)."""
     if not context_axis:
         return None
-    from repro.utils.jax_compat import get_abstract_mesh
-
-    mesh = get_abstract_mesh()
-    if mesh is None or getattr(mesh, "empty", False):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
     if context_axis not in mesh.axis_names:
         return None
@@ -307,6 +306,7 @@ def attend_decode(
     group_size: int = 1,
     scale: float | None = None,
     block_tables: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Decode-path attention dispatch: one (or a few speculative /
     chunked-prefill) query tokens against a KV cache with per-slot live
@@ -318,7 +318,8 @@ def attend_decode(
     KV traffic then scales with the live length, not S.
 
     Paged caches (``block_tables`` (B, max_blocks) int32): k/v are shared
-    (P, Hkv, block_size, d) pools and the KV stream goes through the
+    token-major (P, block_size, Hkv·d) pools — or (L, P, block_size, Hkv·d)
+    stacks read at the traced ``layer`` — and the KV stream goes through the
     scalar-prefetched block table (``kernels.ops.paged_decode_attention``);
     the ``reference`` oracle gathers the table into a contiguous cache
     first (the parity baseline in tests).  Multi-token ``q`` is banded —
@@ -328,17 +329,18 @@ def attend_decode(
     ``reference`` keeps the pure-JAX masked-softmax oracle.  The fused-K̂
     variant is selected by passing ``k_fused`` + ``perm`` + ``group_size``
     (see serve.kv_cache); ``k`` may be None in that case.  ``scale`` always
-    refers to the full head dim (default 1/√d from V) on both paths.
+    refers to the full head dim (default 1/√d, d from ``q``) on both paths.
     """
     if cfg.impl not in IMPLS:
         raise ValueError(
             f"unknown attention impl {cfg.impl!r}; choose from {IMPLS}"
         )
-    scale = float(scale) if scale is not None else 1.0 / (v.shape[-1] ** 0.5)
+    scale = float(scale) if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if block_tables is not None:
         return _attend_decode_paged(
             q, k, v, cfg, lengths=lengths, k_fused=k_fused, perm=perm,
             group_size=group_size, scale=scale, block_tables=block_tables,
+            layer=layer,
         )
     if cfg.impl == "reference":
         from repro.core import grouping
@@ -368,21 +370,27 @@ def attend_decode(
     )
 
 
-def _gather_paged(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
-    """(P, Hkv, bs, d) pool + (B, max_blocks) table → (B, Hkv, max_blocks·bs,
+def _gather_paged(pool: jnp.ndarray, block_tables: jnp.ndarray,
+                  hkv: int) -> jnp.ndarray:
+    """(P, bs, Hkv·d) pool + (B, max_blocks) table → (B, Hkv, max_blocks·bs,
     d) contiguous per-request cache (the reference/oracle materialisation the
     kernel path exists to avoid)."""
-    gathered = jnp.take(pool, block_tables, axis=0)  # (B, mb, Hkv, bs, d)
-    b, mb, hkv, bs, d = gathered.shape
-    return gathered.transpose(0, 2, 1, 3, 4).reshape(b, hkv, mb * bs, d)
+    gathered = jnp.take(pool, block_tables, axis=0)  # (B, mb, bs, Hkv·d)
+    b, mb, bs, width = gathered.shape
+    return gathered.reshape(b, mb * bs, hkv, width // hkv).transpose(0, 2, 1, 3)
 
 
 def _attend_decode_paged(q, k, v, cfg, *, lengths, k_fused, perm, group_size,
-                         scale, block_tables):
+                         scale, block_tables, layer):
     if cfg.impl == "reference":
         from repro.core import grouping
 
-        bs = v.shape[2]
+        if layer is not None:
+            k, v, k_fused = (
+                None if x is None else x[layer] for x in (k, v, k_fused)
+            )
+        bs = v.shape[1]
+        hkv = v.shape[2] // q.shape[-1]
         capacity = block_tables.shape[1] * bs
         # Like the kernel op, lengths are NOT clamped to capacity: a padded
         # window overhanging it must not shift live rows' causal bands.
@@ -397,13 +405,13 @@ def _attend_decode_paged(q, k, v, cfg, *, lengths, k_fused, perm, group_size,
         row = jnp.arange(q_len)[None, :, None]  # (1, q_len, 1)
         # Banded live window per query row (degenerate for q_len = 1).
         band = col < (lengths[:, None, None] - (q_len - 1 - row))
-        v_c = _gather_paged(v, block_tables).astype(q.dtype)
+        v_c = _gather_paged(v, block_tables, hkv).astype(q.dtype)
         if k_fused is not None:
             q_r = grouping.sample_q_heads(q, perm, group_size)
-            k_c = _gather_paged(k_fused, block_tables).astype(q.dtype)
+            k_c = _gather_paged(k_fused, block_tables, hkv).astype(q.dtype)
         else:
             q_r = q
-            k_c = _gather_paged(k, block_tables).astype(q.dtype)
+            k_c = _gather_paged(k, block_tables, hkv).astype(q.dtype)
         outs = [
             reference_attention(
                 q_r[:, :, i : i + 1], k_c, v_c, causal=False, scale=scale,
@@ -417,5 +425,5 @@ def _attend_decode_paged(q, k, v, cfg, *, lengths, k_fused, perm, group_size,
     return ops.paged_decode_attention(
         q, k, v, block_tables=block_tables, lengths=lengths,
         k_fused_pool=k_fused, perm=perm, group_size=group_size, scale=scale,
-        interpret=cfg.interpret,
+        layer=layer, interpret=cfg.interpret,
     )

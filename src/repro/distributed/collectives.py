@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.utils.jax_compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -54,7 +53,7 @@ def ring_allgather_matmul(x: jnp.ndarray, w: jnp.ndarray, mesh, axis: str = "mod
             )
         return acc
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axis, None)),
@@ -82,7 +81,7 @@ def psum_scatter_matmul(x: jnp.ndarray, w: jnp.ndarray, mesh, axis: str = "model
         return jax.lax.psum_scatter(partial, axis, scatter_dimension=partial.ndim - 1,
                                     tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axis, None)),
@@ -105,4 +104,4 @@ def allreduce_with_compression(grads, mesh, *, compress_fn=None, decompress_fn=N
         return g
 
     spec = P()
-    return shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec)(grads)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec)(grads)

@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.utils.jax_compat import pvary, shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -68,8 +67,8 @@ def pipeline_apply(
             )
             return (outs, nxt), None
 
-        outs0 = pvary(jnp.zeros((m,) + mb_shape, x_local.dtype), (axis,))
-        cur0 = pvary(jnp.zeros(mb_shape, x_local.dtype), (axis,))
+        outs0 = jax.lax.pvary(jnp.zeros((m,) + mb_shape, x_local.dtype), (axis,))
+        cur0 = jax.lax.pvary(jnp.zeros(mb_shape, x_local.dtype), (axis,))
         (outs, _), _ = jax.lax.scan(
             tick, (outs0, cur0), jnp.arange(m + s_total - 1)
         )
@@ -81,7 +80,7 @@ def pipeline_apply(
     stage_specs = jax.tree_util.tree_map(
         lambda t: P(axis, *([None] * (t.ndim - 1))), stage_params
     )
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(stage_specs, P(*([None] * (n_extra + 1)))),

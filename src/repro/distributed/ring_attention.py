@@ -403,8 +403,7 @@ def _ring_distr_local_fwd(meta: _RingMeta, q_hat, perms, k, v):
     return out, lse.reshape(b, hq, n_sh), jax.lax.psum(hops, meta.axis)
 
 
-def _ring_distr_local_bwd(meta: _RingMeta, q_hat, perms, inv_perms, k, v, o,
-                          lse, do):
+def _ring_distr_local_bwd(meta: _RingMeta, q_hat, perms, k, v, o, lse, do):
     """Shard-local ring backward.  All args shard-local; lse is the merged
     (global over KV hops) logsumexp of the local Q rows.  Returns
     (dq_hat, dk, dv) — dq_hat still in sampled space; the global wrapper
@@ -424,7 +423,6 @@ def _ring_distr_local_bwd(meta: _RingMeta, q_hat, perms, inv_perms, k, v, o,
     dof = do.astype(k.dtype).reshape(b * hq, n_sh, d)
     of = o.reshape(b * hq, n_sh, d)
     perm_f = perms.reshape(b * hq, nq_blocks, d)
-    inv_perm_f = inv_perms.reshape(b * hq, nq_blocks, d)
     delta = bwd.delta_kernel_call(
         of, dof, block_q=cfg.block_q, interpret=meta.interpret
     )
@@ -449,7 +447,7 @@ def _ring_distr_local_bwd(meta: _RingMeta, q_hat, perms, inv_perms, k, v, o,
                 interpret=meta.interpret,
             )
             dk_h, dv_h = bwd.distr_dkv_kernel_call(
-                q_hat, k_c, v_c, perm_f, inv_perm_f, dof, lse_b, delta,
+                q_hat, k_c, v_c, perm_f, dof, lse_b, delta,
                 q_per_kv=q_per_kv, causal=kernel_causal, group_size=g,
                 block_q=cfg.block_q, block_k=bk_dkv, kv_len=kv_len,
                 interpret=meta.interpret,
@@ -490,9 +488,7 @@ def _ring_distr_fwd_global(meta, mesh, axis, q, k, v):
     qkv_spec, out_spec = _ring_specs(
         mesh, axis, q.shape[0], q.shape[1], k.shape[1]
     )
-    from repro.utils.jax_compat import shard_map
-
-    res = shard_map(
+    res = jax.shard_map(
         functools.partial(_ring_distr_local_fwd, meta),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, qkv_spec),
@@ -515,18 +511,15 @@ def _ring_distr_vjp_bwd(meta, mesh, axis, res, cts):
     do, _ = cts
     b, hq = o.shape[0], o.shape[1]
     g = cfg.group_size
-    inv_perms = jnp.argsort(perms, axis=-1).astype(perms.dtype)
 
     qkv_spec, out_spec = _ring_specs(mesh, axis, b, hq, k.shape[1])
-    from repro.utils.jax_compat import shard_map
-
-    dq_hat, dk, dv = shard_map(
+    dq_hat, dk, dv = jax.shard_map(
         functools.partial(_ring_distr_local_bwd, meta),
         mesh=mesh,
-        in_specs=(qkv_spec,) * 8,
+        in_specs=(qkv_spec,) * 7,
         out_specs=(qkv_spec, qkv_spec, qkv_spec),
         check_vma=False,
-    )(q_hat, perms, inv_perms, k, v, o, lse[..., None], do)
+    )(q_hat, perms, k, v, o, lse[..., None], do)
 
     dq = ops.distr_dq_from_dq_hat(
         cfg.estimator, dq_hat, perms,
@@ -576,9 +569,7 @@ def _run_ring(local_fn, meta, q, k, v, mesh, axis):
     qkv_spec, out_spec = _ring_specs(
         mesh, axis, q.shape[0], q.shape[1], k.shape[1]
     )
-    from repro.utils.jax_compat import shard_map
-
-    out, hops = shard_map(
+    out, hops = jax.shard_map(
         functools.partial(local_fn, meta),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec),

@@ -13,6 +13,8 @@ exactly like its parameter.  Activations: batch → every non-"model" axis
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -27,6 +29,16 @@ LOGICAL_RULES = {
 }
 
 MIN_FSDP_DIM = 1024
+
+
+@contextlib.contextmanager
+def maybe_set_mesh(mesh):
+    """``jax.sharding.set_mesh(mesh)``, or nothing when ``mesh`` is None."""
+    if mesh is None:
+        yield
+        return
+    with jax.sharding.set_mesh(mesh):
+        yield
 
 # The reserved mesh-axis name for ring sequence-parallel attention
 # (distributed.ring_attention).  The ring functions themselves accept any

@@ -6,8 +6,9 @@ IO-aware recomputation instead of materialising the N×N probability matrix
 (Dao, 2023).  Three kernel families:
 
 * ``delta``  — D = rowsum(dO ∘ O), one cheap VPU pass.  Like the LSE it is
-  stored per-row f32 ``(BHq, N)`` in HBM (no ×128 lane replication); the
-  matmul kernels re-broadcast it to a (block_q, 1) column on load.
+  stored per-row f32 ``(BHq, N)`` in HBM (no ×128 lane replication; the
+  kernels see it in ``row_blocks`` layout); the matmul kernels
+  re-broadcast it to a (block_q, 1) column on load.
 * ``dq``     — grid (B·Hq, N/l, Nk/m), KV innermost, dQ accumulated in VMEM
   scratch across KV blocks:  dQ = Σ_j dS_j K_j · scale.
 * ``dkv``    — grid (B·Hq, Nk/m, N/l), Q innermost, dK/dV accumulated across
@@ -15,10 +16,10 @@ IO-aware recomputation instead of materialising the N×N probability matrix
   per *query* head; the ops.py wrapper sums the ``q_per_kv`` group (GQA).
 
 The distr variants re-fuse K̂ in-kernel under the saved per-Q-block
-permutation (same gather + segment-sum as the forward) and route dK̂ back
-through the segment-sum transpose: each fused column's gradient is replicated
-to its ``G*`` members and scattered to original column order via the inverse
-permutation — a lane *gather* by ``inv_perm``, TPU-friendly, no scatter op.
+permutation (the same one-hot fusion matmul ``K̂ = K·Fᵀ`` as the forward)
+and route dK̂ back through its transpose, ``dK = dK̂·F``: each fused
+column's gradient reaches its ``G*`` member columns in original order — no
+gather or scatter op.
 The LSH permutation itself is non-differentiable (straight-through): the
 paper's grouping is a fixed discrete choice per block, so no gradient flows
 into the hash.  Q̂ gradients leave the kernel in sampled space; the wrapper
@@ -37,9 +38,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.distr_attention import fuse_k_columns
-from repro.kernels.flash_attention import NEG_INF
-from repro.kernels.tpu_compat import CompilerParams
+from repro.kernels.distr_attention import (
+    column_groups,
+    fuse_k_columns,
+    fusion_matrix,
+    groups_spec,
+)
+from repro.kernels.flash_attention import NEG_INF, row_blocks, row_stat_spec
 
 
 # ---------------------------------------------------------------------------
@@ -52,7 +57,7 @@ def _delta_kernel(o_ref, do_ref, d_ref):
     do = do_ref[...].astype(jnp.float32)
     # Per-row f32 write (not lane-replicated): the matmul kernels
     # re-broadcast on load.
-    d_ref[...] = (o * do).sum(axis=1)
+    d_ref[...] = (o * do).sum(axis=1, keepdims=True).reshape(d_ref.shape)
 
 
 def delta_kernel_call(
@@ -72,14 +77,14 @@ def delta_kernel_call(
             pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
             pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
         ],
-        out_specs=pl.BlockSpec((None, block_q), lambda bh, i: (bh, i)),
-        out_shape=jax.ShapeDtypeStruct((bhq, n), jnp.float32),
-        compiler_params=CompilerParams(
+        out_specs=row_stat_spec(block_q, lambda bh, i: (bh, i)),
+        out_shape=jax.ShapeDtypeStruct((bhq, n // block_q, 1, block_q), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
         name="attention_bwd_delta",
-    )(o, do)
+    )(o, do).reshape(bhq, n)
 
 
 # ---------------------------------------------------------------------------
@@ -133,10 +138,10 @@ def _flash_dq_kernel(
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
         do = do_ref[...].astype(jnp.float32)
-        # Per-row residuals: re-broadcast the (block_q,) row stats to the
+        # Per-row residuals: re-broadcast the (1, block_q) row stats to the
         # (block_q, 1) column layout the block math wants.
-        lse = lse_ref[...][:, None]
-        delta = delta_ref[...][:, None]
+        lse = lse_ref[...].reshape(block_q, 1)
+        delta = delta_ref[...].reshape(block_q, 1)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -182,18 +187,18 @@ def flash_dq_kernel_call(
             pl.BlockSpec((None, block_k, d), kv_index),
             pl.BlockSpec((None, block_k, d), kv_index),
             pl.BlockSpec((None, block_q, d), q_index),
-            pl.BlockSpec((None, block_q), lambda bh, i, j: (bh, i)),
-            pl.BlockSpec((None, block_q), lambda bh, i, j: (bh, i)),
+            row_stat_spec(block_q, lambda bh, i, j: (bh, i)),
+            row_stat_spec(block_q, lambda bh, i, j: (bh, i)),
         ],
         out_specs=pl.BlockSpec((None, block_q, d), q_index),
         out_shape=jax.ShapeDtypeStruct((bhq, n, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_attention_bwd_dq",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, row_blocks(lse, block_q), row_blocks(delta, block_q))
 
 
 def _flash_dkv_kernel(
@@ -220,10 +225,10 @@ def _flash_dkv_kernel(
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
         do = do_ref[...].astype(jnp.float32)
-        # Per-row residuals: re-broadcast the (block_q,) row stats to the
+        # Per-row residuals: re-broadcast the (1, block_q) row stats to the
         # (block_q, 1) column layout the block math wants.
-        lse = lse_ref[...][:, None]
-        delta = delta_ref[...][:, None]
+        lse = lse_ref[...].reshape(block_q, 1)
+        delta = delta_ref[...].reshape(block_q, 1)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -275,8 +280,8 @@ def flash_dkv_kernel_call(
             pl.BlockSpec((None, block_k, d), kv_index),
             pl.BlockSpec((None, block_k, d), kv_index),
             pl.BlockSpec((None, block_q, d), q_index),
-            pl.BlockSpec((None, block_q), lambda bh, j, i: (bh, i)),
-            pl.BlockSpec((None, block_q), lambda bh, j, i: (bh, i)),
+            row_stat_spec(block_q, lambda bh, j, i: (bh, i)),
+            row_stat_spec(block_q, lambda bh, j, i: (bh, i)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, d), dkv_index),
@@ -290,12 +295,12 @@ def flash_dkv_kernel_call(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, row_blocks(lse, block_q), row_blocks(delta, block_q))
 
 
 # ---------------------------------------------------------------------------
@@ -304,9 +309,9 @@ def flash_dkv_kernel_call(
 
 
 def _distr_dq_kernel(
-    q_hat_ref, k_ref, v_ref, perm_ref, do_ref, lse_ref, delta_ref,
+    q_hat_ref, k_ref, v_ref, groups_ref, do_ref, lse_ref, delta_ref,
     dq_hat_ref, dq_scr,
-    *, causal, group_size, block_q, block_k, kv_len,
+    *, causal, block_q, block_k, kv_len,
 ):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -326,13 +331,13 @@ def _distr_dq_kernel(
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
         do = do_ref[...].astype(jnp.float32)
-        perm = perm_ref[0]
-        # Per-row residuals: re-broadcast the (block_q,) row stats to the
+        # Per-row residuals: re-broadcast the (1, block_q) row stats to the
         # (block_q, 1) column layout the block math wants.
-        lse = lse_ref[...][:, None]
-        delta = delta_ref[...][:, None]
+        lse = lse_ref[...].reshape(block_q, 1)
+        delta = delta_ref[...].reshape(block_q, 1)
 
-        k_hat = fuse_k_columns(k, perm, group_size)  # (block_k, dg)
+        fuse = fusion_matrix(groups_ref[...], q_hat.shape[1])
+        k_hat = fuse_k_columns(k, fuse)  # (block_k, dg)
         s = jax.lax.dot_general(
             q_hat, k_hat, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -369,8 +374,7 @@ def distr_dq_kernel_call(
 
     kernel = functools.partial(
         _distr_dq_kernel,
-        causal=causal, group_size=group_size, block_q=block_q,
-        block_k=block_k, kv_len=kv_len,
+        causal=causal, block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
     return pl.pallas_call(
         kernel,
@@ -379,26 +383,27 @@ def distr_dq_kernel_call(
             pl.BlockSpec((None, block_q, dg), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, block_k, d), lambda bh, i, j: (bh // q_per_kv, j, 0)),
             pl.BlockSpec((None, block_k, d), lambda bh, i, j: (bh // q_per_kv, j, 0)),
-            pl.BlockSpec((None, 1, d), lambda bh, i, j: (bh, i, 0)),
+            groups_spec(d, lambda bh, i, j: (bh, i)),
             pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((None, block_q), lambda bh, i, j: (bh, i)),
-            pl.BlockSpec((None, block_q), lambda bh, i, j: (bh, i)),
+            row_stat_spec(block_q, lambda bh, i, j: (bh, i)),
+            row_stat_spec(block_q, lambda bh, i, j: (bh, i)),
         ],
         out_specs=pl.BlockSpec((None, block_q, dg), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bhq, n, dg), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, dg), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="distr_attention_bwd_dq",
-    )(q_hat, k, v, perm, do, lse, delta)
+    )(q_hat, k, v, column_groups(perm, group_size), do,
+      row_blocks(lse, block_q), row_blocks(delta, block_q))
 
 
 def _distr_dkv_kernel(
-    q_hat_ref, k_ref, v_ref, perm_ref, inv_perm_ref, do_ref, lse_ref,
-    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-    *, causal, group_size, block_q, block_k, kv_len,
+    q_hat_ref, k_ref, v_ref, groups_ref, do_ref, lse_ref, delta_ref,
+    dk_ref, dv_ref, dk_scr, dv_scr,
+    *, causal, block_q, block_k, kv_len,
 ):
     ik = pl.program_id(1)
     iq = pl.program_id(2)
@@ -419,14 +424,13 @@ def _distr_dkv_kernel(
         k = k_ref[...].astype(jnp.float32)  # (block_k, d)
         v = v_ref[...].astype(jnp.float32)
         do = do_ref[...].astype(jnp.float32)
-        perm = perm_ref[0]  # (d,) this Q block's permutation
-        inv_perm = inv_perm_ref[0]  # (d,) its inverse
-        # Per-row residuals: re-broadcast the (block_q,) row stats to the
+        # Per-row residuals: re-broadcast the (1, block_q) row stats to the
         # (block_q, 1) column layout the block math wants.
-        lse = lse_ref[...][:, None]
-        delta = delta_ref[...][:, None]
+        lse = lse_ref[...].reshape(block_q, 1)
+        delta = delta_ref[...].reshape(block_q, 1)
 
-        k_hat = fuse_k_columns(k, perm, group_size)  # re-fused under this Q block
+        fuse = fusion_matrix(groups_ref[...], q_hat.shape[1])
+        k_hat = fuse_k_columns(k, fuse)  # re-fused under this Q block
         s = jax.lax.dot_general(
             q_hat, k_hat, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -443,14 +447,13 @@ def _distr_dkv_kernel(
             ds, q_hat, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (block_k, dg)
-        # Segment-sum transpose: every member of a fused group receives the
-        # group's gradient; undo the permutation with a gather by inv_perm
-        # (dk[:, c] = dk_rep[:, inv_perm[c]] since perm[inv_perm[c]] = c).
-        d = k.shape[1]
-        dk_rep = jnp.broadcast_to(
-            dk_hat[:, :, None], (dk_hat.shape[0], dk_hat.shape[1], group_size)
-        ).reshape(dk_hat.shape[0], d)
-        dk_scr[...] += jnp.take(dk_rep, inv_perm, axis=1)
+        # Transpose of the fusion: every member of a fused group receives
+        # the group's gradient, in original column order.
+        dk_scr[...] += jax.lax.dot_general(
+            dk_hat, fuse, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(iq == nq - 1)
     def _finalize():
@@ -459,7 +462,7 @@ def _distr_dkv_kernel(
 
 
 def distr_dkv_kernel_call(
-    q_hat, k, v, perm, inv_perm, do, lse, delta, *,
+    q_hat, k, v, perm, do, lse, delta, *,
     q_per_kv: int, causal: bool, group_size: int,
     block_q: int, block_k: int, kv_len: int, interpret: bool = True,
 ):
@@ -477,8 +480,7 @@ def distr_dkv_kernel_call(
 
     kernel = functools.partial(
         _distr_dkv_kernel,
-        causal=causal, group_size=group_size, block_q=block_q,
-        block_k=block_k, kv_len=kv_len,
+        causal=causal, block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
     return pl.pallas_call(
         kernel,
@@ -487,11 +489,10 @@ def distr_dkv_kernel_call(
             pl.BlockSpec((None, block_q, dg), q_index),
             pl.BlockSpec((None, block_k, d), kv_index),
             pl.BlockSpec((None, block_k, d), kv_index),
-            pl.BlockSpec((None, 1, d), q_index),
-            pl.BlockSpec((None, 1, d), q_index),
+            groups_spec(d, lambda bh, j, i: (bh, i)),
             pl.BlockSpec((None, block_q, d), q_index),
-            pl.BlockSpec((None, block_q), lambda bh, j, i: (bh, i)),
-            pl.BlockSpec((None, block_q), lambda bh, j, i: (bh, i)),
+            row_stat_spec(block_q, lambda bh, j, i: (bh, i)),
+            row_stat_spec(block_q, lambda bh, j, i: (bh, i)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, d), dkv_index),
@@ -505,9 +506,10 @@ def distr_dkv_kernel_call(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="distr_attention_bwd_dkv",
-    )(q_hat, k, v, perm, inv_perm, do, lse, delta)
+    )(q_hat, k, v, column_groups(perm, group_size), do,
+      row_blocks(lse, block_q), row_blocks(delta, block_q))

@@ -60,7 +60,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import NEG_INF
-from repro.kernels.tpu_compat import CompilerParams
 
 ROW_ALIGN = 8  # f32 sublane width: the wrapper pads packed rows to this
 
@@ -71,8 +70,8 @@ def _decode_kernel(
     k_ref,  # (1, 1, block_k, d_score)
     v_ref,  # (1, 1, block_k, d)
     o_ref,  # (1, 1, 1, rows, d)      unnormalised partial
-    m_ref,  # (1, 1, 1, rows)         per-split row max
-    l_ref,  # (1, 1, 1, rows)         per-split row sum
+    m_ref,  # (1, rows)               per-split row max
+    l_ref,  # (1, rows)               per-split row sum
     *,
     scale: float,
     block_k: int,
@@ -105,19 +104,19 @@ def _decode_kernel(
         mask = col < row_len
         s = jnp.where(mask, s, NEG_INF)
 
-        m = s.max(axis=1)  # (rows,)
-        p = jnp.where(mask, jnp.exp(s - m[:, None]), 0.0)
+        m = s.max(axis=1, keepdims=True)  # (rows, 1)
+        p = jnp.where(mask, jnp.exp(s - m), 0.0)
         o_ref[0, 0, 0] = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        m_ref[0, 0, 0] = m
-        l_ref[0, 0, 0] = p.sum(axis=1)
+        m_ref[...] = m.reshape(m_ref.shape)
+        l_ref[...] = p.sum(axis=1, keepdims=True).reshape(l_ref.shape)
 
     @pl.when(jnp.logical_not(live))
     def _dead():
         o_ref[0, 0, 0] = jnp.zeros_like(o_ref[0, 0, 0])
-        m_ref[0, 0, 0] = jnp.full_like(m_ref[0, 0, 0], NEG_INF)
-        l_ref[0, 0, 0] = jnp.zeros_like(l_ref[0, 0, 0])
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
 
 def decode_kernel_call(
@@ -157,9 +156,6 @@ def decode_kernel_call(
     def out_index(bi, h, j, lens):
         return (bi, h, j, 0, 0)
 
-    def stat_index(bi, h, j, lens):
-        return (bi, h, j, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hkv, splits),
@@ -170,27 +166,30 @@ def decode_kernel_call(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, rows, d), out_index),
-            pl.BlockSpec((1, 1, 1, rows), stat_index),
-            pl.BlockSpec((1, 1, 1, rows), stat_index),
+            # Row stats carry a unit axis: Mosaic wants a block's last two
+            # dims whole or (8, 128)-aligned, and (1, rows) is whole.
+            pl.BlockSpec((None, None, None, 1, rows), out_index),
+            pl.BlockSpec((None, None, None, 1, rows), out_index),
         ],
     )
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_k=block_k, q_len=q_len
     )
-    return pl.pallas_call(
+    o, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, splits, rows, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, splits, rows), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, splits, rows), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, splits, 1, rows), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, splits, 1, rows), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
         name="flash_decode_splitk",
     )(lengths, q, k, v)
+    return o, m[..., 0, :], l[..., 0, :]
 
 
 def merge_splits(o: jnp.ndarray, m: jnp.ndarray, l: jnp.ndarray) -> jnp.ndarray:

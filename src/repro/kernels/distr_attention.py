@@ -7,15 +7,19 @@ Differences from the exact flash kernel:
   runs grouping as a separate lightweight stage, §4.8) and Q-sampling is a
   cheap one-off gather there.
 * Each KV block is **fused in-kernel** under the current Q-block's
-  permutation: gather K's d columns by ``perm`` then segment-sum runs of
-  ``G*``.  This must live in the kernel: K̂ depends on (Q block, K block)
-  jointly, and materialising it outside would cost O(N²·d/G*) memory.
+  permutation: K's d columns are permuted by ``perm`` and each run of
+  ``G*`` is summed.  This must live in the kernel: K̂ depends on (Q block,
+  K block) jointly, and materialising it outside would cost O(N²·d/G*)
+  memory.
 * The score matmul contracts over ``d/G*`` instead of ``d`` — the paper's
   compute reduction.  V and the PV matmul are untouched (full context).
 
-TPU note (DESIGN.md §2): the column gather runs on the VPU (lane shuffles /
-one-hot matmul under Mosaic), freeing MXU cycles; on GPUs the paper uses warp
-shuffles.  Validated against ``ref.distr_attention_ref`` in interpret mode.
+TPU note (DESIGN.md §2): Mosaic cannot gather lanes by a dynamic index
+vector, so the permute-and-sum runs as one matmul against a one-hot
+``(d/G*, d)`` matrix built from the block's column groups (``fusion_matrix``);
+on GPUs the paper uses warp shuffles.  Validated against
+``ref.distr_attention_ref`` in interpret mode and compiled for v5e in
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -26,28 +30,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import NEG_INF, STATS_LANES
-from repro.kernels.tpu_compat import CompilerParams
+from repro.kernels.flash_attention import NEG_INF, STATS_LANES, row_stat_spec
 
 
-def fuse_k_columns(k, perm, group_size: int):
-    """The paper's fusion: permute K's columns by ``perm``, segment-sum runs
-    of ``G*``.  Shared by the forward and backward kernels — the backward's
-    recomputed K̂ must be bit-identical to what produced the saved LSE."""
-    k_perm = jnp.take(k, perm, axis=1)  # lane gather (VPU)
-    d = k.shape[1]
-    return k_perm.reshape(k.shape[0], d // group_size, group_size).sum(axis=2)
+def column_groups(perm: jnp.ndarray, group_size: int) -> jnp.ndarray:
+    """(BH, nq, d) per-Q-block permutations → (BH, nq, 1, d) int32: the
+    fused column each of K's d columns lands in (``argsort(perm) // G*``).
+    The unit axis makes one block's ``(1, d)`` row a whole tile for Mosaic."""
+    groups = jnp.argsort(perm, axis=-1) // group_size
+    return groups.astype(jnp.int32)[:, :, None, :]
+
+
+def fusion_matrix(groups: jnp.ndarray, n_groups: int) -> jnp.ndarray:
+    """One-hot ``F`` (n_groups, d) f32 with ``F[g, c] = 1`` iff K column
+    ``c`` is in fused group ``g``; ``groups`` is one ``(1, d)`` row of
+    ``column_groups``."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n_groups, groups.shape[-1]), 0)
+    return (rows == groups).astype(jnp.float32)
+
+
+def fuse_k_columns(k, fuse):
+    """The paper's fusion — permute K's columns, sum each run of ``G*`` —
+    as the matmul ``K̂ = K·Fᵀ`` (Mosaic has no lane gather by a dynamic
+    index vector).  Shared by the forward and backward kernels: the
+    backward's recomputed K̂ must be bit-identical to what produced the
+    saved LSE."""
+    return jax.lax.dot_general(
+        k, fuse, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def groups_spec(d: int, index_map) -> pl.BlockSpec:
+    """BlockSpec of one Q block's ``column_groups`` row; ``index_map``
+    returns ``(bh, q_block)``."""
+    return pl.BlockSpec((None, None, 1, d), lambda *g: (*index_map(*g), 0, 0))
 
 
 def _distr_kernel(
     q_hat_ref,
     k_ref,
     v_ref,
-    perm_ref,
+    groups_ref,
     o_ref,
     *rest,
     causal: bool,
-    group_size: int,
     block_q: int,
     block_k: int,
     kv_len: int,
@@ -76,10 +104,9 @@ def _distr_kernel(
         q_hat = q_hat_ref[...].astype(jnp.float32)  # (block_q, dg) pre-scaled
         k = k_ref[...].astype(jnp.float32)  # (block_k, d)
         v = v_ref[...].astype(jnp.float32)  # (block_k, d)
-        perm = perm_ref[0]  # (d,) int32 — this Q block's permutation
-
-        # --- the paper's fusion: permute K columns, sum each run of G*.
-        k_hat = fuse_k_columns(k, perm, group_size)
+        # --- the paper's fusion under this Q block's permutation.
+        fuse = fusion_matrix(groups_ref[...], q_hat.shape[1])
+        k_hat = fuse_k_columns(k, fuse)
 
         s = jax.lax.dot_general(
             q_hat, k_hat, (((1,), (1,)), ((), ())),
@@ -114,7 +141,7 @@ def _distr_kernel(
         if with_lse:
             m_final = m_scr[...][:, :1]
             lse = jnp.where(l_final == 0.0, NEG_INF, m_final + jnp.log(denom))
-            lse_ref[...] = lse[:, 0]  # per-row f32 (not lane-replicated)
+            lse_ref[...] = lse.reshape(1, block_q)  # per-row f32
 
 
 def distr_attention_kernel_call(
@@ -151,7 +178,6 @@ def distr_attention_kernel_call(
     kernel = functools.partial(
         _distr_kernel,
         causal=causal,
-        group_size=group_size,
         block_q=block_q,
         block_k=block_k,
         kv_len=kv_len,
@@ -160,22 +186,19 @@ def distr_attention_kernel_call(
     out_specs = pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0))
     out_shape = jax.ShapeDtypeStruct((bhq, n, d), q_hat.dtype)
     if return_residuals:
-        out_specs = [
-            out_specs,
-            pl.BlockSpec((None, block_q), lambda bh, i, j: (bh, i)),
-        ]
+        out_specs = [out_specs, row_stat_spec(block_q, lambda bh, i, j: (bh, i))]
         out_shape = [
             out_shape,
-            jax.ShapeDtypeStruct((bhq, n), jnp.float32),
+            jax.ShapeDtypeStruct((bhq, n // block_q, 1, block_q), jnp.float32),
         ]
-    return pl.pallas_call(
+    res = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, dg), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, block_k, d), lambda bh, i, j: (bh // q_per_kv, j, 0)),
             pl.BlockSpec((None, block_k, d), lambda bh, i, j: (bh // q_per_kv, j, 0)),
-            pl.BlockSpec((None, 1, d), lambda bh, i, j: (bh, i, 0)),
+            groups_spec(d, lambda bh, i, j: (bh, i)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -184,9 +207,13 @@ def distr_attention_kernel_call(
             pltpu.VMEM((block_q, STATS_LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="distr_attention_fwd",
-    )(q_hat, k, v, perm)
+    )(q_hat, k, v, column_groups(perm, group_size))
+    if return_residuals:
+        o, lse = res
+        return o, lse.reshape(bhq, n)
+    return res

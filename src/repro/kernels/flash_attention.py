@@ -7,17 +7,19 @@ KV-block iteration is the innermost ("arbitrary") grid dimension so the
 inner loop held in registers/SMEM.
 
 With ``return_residuals=True`` the kernel additionally emits the per-row
-logsumexp ``L = m + log l`` as a plain ``(BHq, N)`` f32 row vector — the
-only softmax statistic the FA-2 backward needs; dQ/dK/dV then recompute
-the score blocks instead of materialising them (kernels/backward.py).
-Only the VMEM scratch keeps the lane-replicated ``(block_q, 128)`` layout
-(TPU vector layouts want a lane-width minor dim); the HBM residual is
-per-row — 128× less stats traffic than replicating the scratch layout out
-(DESIGN.md §Backward).
+logsumexp ``L = m + log l`` as a ``(BHq, N)`` f32 row vector — the only
+softmax statistic the FA-2 backward needs; dQ/dK/dV then recompute the
+score blocks instead of materialising them (kernels/backward.py).  Only
+the VMEM scratch keeps the lane-replicated ``(block_q, 128)`` layout (TPU
+vector layouts want a lane-width minor dim); the HBM residual is per-row —
+128× less stats traffic than replicating the scratch layout out
+(DESIGN.md §Backward).  Inside the pallas_call a per-row statistic travels
+as ``(BHq, N/block_q, 1, block_q)`` (``row_blocks``): Mosaic requires a
+block's last two dims to be (8, 128)-aligned or whole, and ``(1, block_q)``
+is whole for any ``block_q``.
 
-Validated against ``ref.flash_attention_ref`` under ``interpret=True`` (this
-container is CPU-only); on real TPUs the ops.py wrapper auto-selects
-compiled mode.
+Validated against ``ref.flash_attention_ref`` under ``interpret=True`` on
+CPU; on TPU the ops.py wrapper selects compiled mode.
 """
 from __future__ import annotations
 
@@ -28,14 +30,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
-
 NEG_INF = -1e30
 # In-kernel softmax-stat *scratch* is lane-replicated: TPU vector layouts
 # want the minor dimension to be a multiple of the 128-lane width.  HBM
 # residuals (LSE, D) are per-row f32 — re-broadcast on load in the backward
 # kernels (one sublane↔lane relayout per block, vs 128× the HBM traffic).
 STATS_LANES = 128
+
+
+def row_blocks(x: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(BH, N) per-row statistic → (BH, N/block, 1, block), the layout a
+    kernel reads or writes it in (see module docstring)."""
+    return x.reshape(x.shape[0], x.shape[1] // block, 1, block)
+
+
+def row_stat_spec(block: int, index_map) -> pl.BlockSpec:
+    """BlockSpec of one ``(1, block)`` row-statistic tile of ``row_blocks``
+    layout; ``index_map`` returns ``(bh, q_block)``."""
+    return pl.BlockSpec(
+        (None, None, 1, block), lambda *g: (*index_map(*g), 0, 0)
+    )
 
 
 def _flash_kernel(
@@ -112,7 +126,7 @@ def _flash_kernel(
             lse = jnp.where(
                 l_final == 0.0, NEG_INF, m_final + jnp.log(denom)
             )
-            lse_ref[...] = lse[:, 0]  # per-row f32 (not lane-replicated)
+            lse_ref[...] = lse.reshape(1, block_q)  # per-row f32
 
 
 def flash_attention_kernel_call(
@@ -164,15 +178,12 @@ def flash_attention_kernel_call(
     out_specs = pl.BlockSpec((None, block_q, d), q_index)
     out_shape = jax.ShapeDtypeStruct((bhq, n, d), q.dtype)
     if return_residuals:
-        out_specs = [
-            out_specs,
-            pl.BlockSpec((None, block_q), lambda bh, i, j: (bh, i)),
-        ]
+        out_specs = [out_specs, row_stat_spec(block_q, lambda bh, i, j: (bh, i))]
         out_shape = [
             out_shape,
-            jax.ShapeDtypeStruct((bhq, n), jnp.float32),
+            jax.ShapeDtypeStruct((bhq, n // block_q, 1, block_q), jnp.float32),
         ]
-    return pl.pallas_call(
+    res = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -187,9 +198,13 @@ def flash_attention_kernel_call(
             pltpu.VMEM((block_q, STATS_LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_attention_fwd",
     )(q, k, v)
+    if return_residuals:
+        o, lse = res
+        return o, lse.reshape(bhq, n)
+    return res

@@ -13,9 +13,9 @@ the kernel path (DESIGN.md §Backward).  The DistrAttention backward treats
 the LSH permutation as non-differentiable (straight-through): gradients
 flow through the Q-sampling gather and the K̂ segment-sum only.
 
-``interpret=None`` (the default everywhere) auto-detects the backend:
-compiled kernels on TPU, interpreter mode elsewhere — no call-site changes
-between the CPU container and real hardware.
+``interpret=None`` (the default everywhere) picks the mode from the
+backend: compiled kernels on TPU, interpreter mode elsewhere — no call-site
+changes between a CPU host and real hardware.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ from repro.tune.cache import dtype_str as _dtype_str
 
 
 def default_interpret() -> bool:
-    """Compiled Pallas on TPU, interpreter everywhere else (CPU container)."""
+    """Compiled Pallas on TPU, interpreter everywhere else (CPU hosts)."""
     return jax.default_backend() != "tpu"
 
 
@@ -421,9 +421,6 @@ def _distr_vjp_bwd(cfg, causal, scale, interpret, res, do):
     kf2, vf2 = (kf1, vf1) if bk_dkv == bk_dq else kv_side(bk_dkv)
     dof, of = _flatten_heads(dop), _flatten_heads(op)
     perm_f = perms.reshape(b * hq, nq_blocks, d)
-    # A permutation's inverse is its argsort; the dkv kernel turns the
-    # segment-sum transpose (scatter-add over perm) into a gather by it.
-    inv_perm_f = jnp.argsort(perm_f, axis=-1).astype(perm_f.dtype)
 
     delta = bwd.delta_kernel_call(of, dof, block_q=cfg.block_q, interpret=interpret)
     dq_hat = bwd.distr_dq_kernel_call(
@@ -433,7 +430,7 @@ def _distr_vjp_bwd(cfg, causal, scale, interpret, res, do):
         interpret=interpret,
     )
     dk_h, dv_h = bwd.distr_dkv_kernel_call(
-        q_hat, kf2, vf2, perm_f, inv_perm_f, dof, lse, delta,
+        q_hat, kf2, vf2, perm_f, dof, lse, delta,
         q_per_kv=q_per_kv, causal=causal, group_size=g,
         block_q=cfg.block_q, block_k=bk_dkv, kv_len=kv_len,
         interpret=interpret,
@@ -627,11 +624,11 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_impl(q_packed, k_pool, v_pool, block_tables, lengths, *,
-                       hq, rows_live, scale, q_len, interpret):
+def _paged_decode_impl(q_packed, k_pool, v_pool, block_tables, lengths,
+                       layer, *, hq, rows_live, scale, q_len, interpret):
     o, m, l = paged_decode_kernels.paged_decode_kernel_call(
         q_packed, k_pool, v_pool, block_tables, lengths,
-        scale=scale, q_len=q_len, interpret=interpret,
+        scale=scale, q_len=q_len, layer=layer, interpret=interpret,
     )
     return _unpack_gqa_rows(
         decode_kernels.merge_splits(o, m, l), rows_live, hq
@@ -642,11 +639,11 @@ def _paged_decode_impl(q_packed, k_pool, v_pool, block_tables, lengths, *,
     jax.jit, static_argnames=("scale", "q_len", "interpret")
 )
 def _paged_decode_attention_jit(q, k_pool, v_pool, block_tables, lengths,
-                                scale, q_len, interpret):
-    hq, hkv = q.shape[1], k_pool.shape[1]
+                                layer, scale, q_len, interpret):
+    hq, hkv = q.shape[1], v_pool.shape[-1] // q.shape[-1]
     q_packed, rows_live = _pack_gqa_rows(q, hkv)
     out = _paged_decode_impl(
-        q_packed, k_pool, v_pool, block_tables, lengths, hq=hq,
+        q_packed, k_pool, v_pool, block_tables, lengths, layer, hq=hq,
         rows_live=rows_live, scale=scale, q_len=q_len, interpret=interpret,
     )
     return out.astype(q.dtype)
@@ -656,15 +653,15 @@ def _paged_decode_attention_jit(q, k_pool, v_pool, block_tables, lengths,
     jax.jit, static_argnames=("group_size", "scale", "q_len", "interpret")
 )
 def _paged_decode_attention_fused_jit(q, k_fused_pool, v_pool, perm,
-                                      block_tables, lengths, group_size,
-                                      scale, q_len, interpret):
-    hq, hkv = q.shape[1], k_fused_pool.shape[1]
+                                      block_tables, lengths, layer,
+                                      group_size, scale, q_len, interpret):
+    hq, hkv = q.shape[1], v_pool.shape[-1] // q.shape[-1]
     # Static per-KV-head permutation, same as the contiguous fused decode —
     # paged decode has no per-Q-block LSH stage (serve.kv_cache.static_perms).
     q_s = grouping.sample_q_heads(q, perm, group_size)
     q_packed, rows_live = _pack_gqa_rows(q_s, hkv)
     out = _paged_decode_impl(
-        q_packed, k_fused_pool, v_pool, block_tables, lengths, hq=hq,
+        q_packed, k_fused_pool, v_pool, block_tables, lengths, layer, hq=hq,
         rows_live=rows_live, scale=scale, q_len=q_len, interpret=interpret,
     )
     return out.astype(q.dtype)
@@ -681,31 +678,34 @@ def paged_decode_attention(
     perm: jnp.ndarray | None = None,
     group_size: int = 1,
     scale: float | None = None,
+    layer: jnp.ndarray | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Block-table split-K flash-decoding over a paged KV pool
     (kernels/paged_decode.py).
 
     q: (B, Hq, q_len, d) with q_len small (1, or a chunked-prefill window);
-    k_pool, v_pool: (P, Hkv, block_size, d) shared block pools;
+    k_pool, v_pool: (P, block_size, Hkv·d) shared token-major block pools
+    (``kernels.paged_decode.token_major``), or the model's (L, P,
+    block_size, Hkv·d) stacks with a traced ``layer``;
     ``block_tables``: (B, max_blocks) int32 physical block ids (logical
     block j of request b lives at ``block_tables[b, j]``); ``lengths``:
     (B,) live token counts — the kernel streams ``ceil(length/block_size)``
     pool blocks per request through scalar-prefetched, clamped index maps.
 
-    Distr fused-K̂ variant: pass ``k_fused_pool`` (P, Hkv, block_size,
-    d/G*), the layer's static ``perm`` (Hkv, d) and ``group_size`` — the
+    Distr fused-K̂ variant: pass ``k_fused_pool`` (P, block_size,
+    Hkv·d/G*), the layer's static ``perm`` (Hkv, d) and ``group_size`` — the
     score stage streams the narrow fused pool (column-sampled Q), the value
     stage full V; ``k_pool`` may be None (raw K stays cold on the paged
     serve path).  ``scale`` always refers to the full head dim (default
     1/√d).  ``interpret=None`` auto-detects the backend.
     """
-    d = v_pool.shape[-1]
+    d = q.shape[-1]
     q_len = q.shape[2]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     if interpret is None:
         interpret = default_interpret()
-    block_size = v_pool.shape[2]
+    block_size = v_pool.shape[-2]
     capacity = block_tables.shape[1] * block_size
     if lengths is None:
         # None ⇒ every table position live (contiguous-op convention).
@@ -724,11 +724,12 @@ def paged_decode_attention(
         if perm is None or group_size <= 1:
             raise ValueError("k_fused_pool needs perm and group_size > 1")
         return _paged_decode_attention_fused_jit(
-            q, k_fused_pool, v_pool, perm, block_tables, lengths, group_size,
-            scale, q_len, interpret,
+            q, k_fused_pool, v_pool, perm, block_tables, lengths, layer,
+            group_size, scale, q_len, interpret,
         )
     return _paged_decode_attention_jit(
-        q, k_pool, v_pool, block_tables, lengths, scale, q_len, interpret
+        q, k_pool, v_pool, block_tables, lengths, layer, scale, q_len,
+        interpret,
     )
 
 

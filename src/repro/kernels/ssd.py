@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tpu_compat import CompilerParams
 
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *, chunk: int):
@@ -102,7 +101,7 @@ def ssd_kernel_call(
         out_specs=pl.BlockSpec((None, chunk, p), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((s, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

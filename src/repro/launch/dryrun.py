@@ -23,7 +23,6 @@ import traceback
 
 import jax
 
-from repro.utils.jax_compat import set_mesh
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -136,7 +135,7 @@ def build_lowered(arch: str, shape_name: str, *, multi_pod: bool,
         "tpu_memory_estimate": tpu_memory_estimate(cfg, shape, mesh, p_shapes),
     }
 
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             o_shapes = jax.eval_shape(adamw_init, p_shapes)
             o_shard = {

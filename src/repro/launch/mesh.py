@@ -10,14 +10,13 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax; all meshes here are
-    Auto-typed, which is also the old default — pass it only when it exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto-typed: the sharding rules
+    (distributed.sharding, models.layers.constrain) hint layouts and let
+    GSPMD propagate the rest."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     (gradient all-reduce crosses the inter-pod links only once per step)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1, context_parallel: int = 1):
@@ -45,9 +44,9 @@ def make_host_mesh(model_parallel: int = 1, context_parallel: int = 1):
             f"device count)"
         )
     if context_parallel > 1:
-        return compat_make_mesh(
+        return make_mesh(
             (n // (model_parallel * context_parallel), context_parallel,
              model_parallel),
             ("data", "context", "model"),
         )
-    return compat_make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))

@@ -1,7 +1,9 @@
-"""Serving launcher: continuous-batching engine over a (reduced) model.
+"""Serving launcher: continuous-batching engine over a model.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-32b --reduced \
       --requests 8 --max-new 16
+
+Weights are held in the config's compute dtype (bf16 at published widths).
 
 ``--trace PATH`` records per-request lifecycle spans (admit → prefill →
 decode → terminal) as a Chrome trace_event JSON loadable in Perfetto;
@@ -22,6 +24,7 @@ from repro.models import lm
 from repro.obs import TraceRecorder, perf_clock, serving_registry
 from repro.serve.engine import ServeEngine
 from repro.train import checkpoint as ckpt
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -39,14 +42,18 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write a typed metrics snapshot of the run")
     args = ap.parse_args()
+    print(f"[serve] compile cache: {use_compile_cache()}")
 
     cfg = get_config(args.arch, reduced=args.reduced)
     key = jax.random.PRNGKey(0)
     if args.ckpt:
         template = jax.eval_shape(lambda k: lm.init_params(k, cfg), key)
         _, params, _, _ = ckpt.load_checkpoint(args.ckpt, template)
+        params = jax.tree_util.tree_map(
+            lambda x: x.astype(cfg.compute_dtype), params
+        )
     else:
-        params = lm.init_params(key, cfg)
+        params = lm.init_serving_params(key, cfg)
 
     rec = TraceRecorder() if args.trace else None
     eng = ServeEngine(cfg, params, max_slots=args.max_slots,

@@ -22,6 +22,7 @@ from repro.train.anomaly import AnomalyConfig
 from repro.train.data import BinaryShardData, SyntheticLMData
 from repro.train.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -70,6 +71,7 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write a typed metrics snapshot of the run")
     args = ap.parse_args()
+    print(f"[train] compile cache: {use_compile_cache()}")
 
     if args.tune:
         os.environ["REPRO_TUNE"] = args.tune
@@ -94,7 +96,7 @@ def main() -> None:
     # timing run.  Explicit config ints pass through untouched.  Under the
     # mesh context the tuner keys go per-shard when context parallelism is
     # on (the ring streams one shard per device, not the global sequence).
-    from repro.utils.jax_compat import maybe_set_mesh
+    from repro.distributed.sharding import maybe_set_mesh
 
     acfg = cfg.attention
     if acfg.impl != "reference" and (acfg.block_q is None or acfg.block_k is None):

@@ -242,27 +242,31 @@ def paged_insert(
     block_tables: jnp.ndarray,
     pos: jnp.ndarray,
     count: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Insert a token window into a paged KV pool through the block table.
 
-    pool: (P, Hkv, bs, d); new: (B, Hkv, w, d); block_tables: (B,
-    max_blocks) physical ids; pos: (B,) absolute start positions.  Token
-    ``t`` of request ``b`` lands at block ``bt[b, (pos+t)//bs]`` offset
-    ``(pos+t) mod bs``.  ``count`` (B,) gates writes: rows ``t ≥ count[b]``
-    (padding in a chunked prefill, idle decode lanes) are redirected to the
-    reserved garbage block so they can never corrupt live KV.
+    pool: token-major (P, bs, Hkv·d) (kernels/paged_decode.py), or the
+    (L, P, bs, Hkv·d) stack written at the traced ``layer``; new: (B, Hkv,
+    w, d); block_tables: (B, max_blocks) physical ids; pos: (B,) absolute
+    start positions.  Token ``t`` of request ``b`` lands at block
+    ``bt[b, (pos+t)//bs]`` row ``(pos+t) mod bs``.  ``count`` (B,) gates
+    writes: rows ``t ≥ count[b]`` (padding in a chunked prefill, idle
+    decode lanes) are redirected to the reserved garbage block so they can
+    never corrupt live KV.
     """
     from repro.kernels.paged_decode import GARBAGE_BLOCK
 
-    bs = pool.shape[2]
+    bs = pool.shape[-2]
     b, hkv, w, d = new.shape
     max_blocks = block_tables.shape[1]
-    # One vectorised scatter over all B·w writes (a loop of per-token
-    # dynamic_update_slice would copy the whole pool per write on
-    # non-donating backends).  Distinct live (block, offset) pairs never
-    # collide — distinct requests own distinct blocks — and any number of
-    # masked writes may collide on the garbage block, whose content is
-    # never read.
+    # One vectorised scatter of whole token rows over all B·w writes (a
+    # loop of per-token dynamic_update_slice would copy the whole pool per
+    # write on non-donating backends).  A row is the scatter's window, so
+    # the pool keeps the layout the decode kernel reads and XLA inserts no
+    # layout copy.  Distinct live (block, row) pairs never collide —
+    # distinct requests own distinct blocks — and any number of masked
+    # writes may collide on the garbage block, whose content is never read.
     p = pos[:, None] + jnp.arange(w)[None, :]  # (B, w) absolute positions
     blk_idx = jnp.minimum(p // bs, max_blocks - 1)
     blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)  # (B, w)
@@ -273,10 +277,11 @@ def paged_insert(
     # clamped blk_idx would silently overwrite the LAST live block.
     live = live & (p < max_blocks * bs)
     blk = jnp.where(live, blk, GARBAGE_BLOCK)
-    vals = new.astype(pool.dtype).transpose(0, 2, 1, 3).reshape(
-        b * w, hkv, d
-    )
-    return pool.at[blk.reshape(-1), :, (p % bs).reshape(-1), :].set(vals)
+    rows = new.astype(pool.dtype).transpose(0, 2, 1, 3).reshape(b * w, hkv * d)
+    idx = (blk.reshape(-1), (p % bs).reshape(-1))
+    if layer is not None:
+        idx = (layer,) + idx
+    return pool.at[idx].set(rows)
 
 
 def attention_decode_paged(
@@ -291,9 +296,12 @@ def attention_decode_paged(
     count: jnp.ndarray | None = None,
     pool_k_fused: jnp.ndarray | None = None,
     perm: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,
 ):
     """Windowed decode against the paged block pool (w = 1 for token
-    decode, w = chunk width for chunked prefill).
+    decode, w = chunk width for chunked prefill).  Pools are one layer's,
+    or the model's stacks read and written in place at the traced
+    ``layer``.
 
     x: (B, w, d_model); ``cache_index``: (B,) absolute start positions;
     ``count``: (B,) live tokens in this window (padding rows write to the
@@ -324,32 +332,34 @@ def attention_decode_paged(
         q = layers.apply_rope(q, positions, cfg.rope_theta)
         k = layers.apply_rope(k, positions, cfg.rope_theta)
 
-    capacity = block_tables.shape[1] * pool_v.shape[2]
+    capacity = block_tables.shape[1] * pool_v.shape[-2]
     wpos = pos % capacity
-    pool_v = paged_insert(pool_v, v, block_tables, wpos, count)
+    pool_v = paged_insert(pool_v, v, block_tables, wpos, count, layer)
     scale = 1.0 / (cfg.head_dim_**0.5)
     # Kernel lengths include the whole window (pos + w): live row t's band
     # col < pos + t + 1 then lands exactly on its own position; padded rows
-    # only ever widen *their own* (discarded) reads.  Past capacity every
-    # pool position is live (the ring overwrote the oldest), so the band
-    # clamps to the full table.
-    lengths = jnp.minimum(pos + w, capacity)
+    # only ever widen *their own* (discarded) reads.  A token decode past
+    # capacity sees every pool position live (the ring overwrote the
+    # oldest), so its band clamps to the full table.  A prefill window is
+    # never clamped: prompts stay below capacity and only its padded rows
+    # overhang it, and a clamp would cut the live rows' bands short.
+    lengths = jnp.minimum(pos + w, capacity) if w == 1 else pos + w
     if pool_k_fused is not None:
         g = cfg.attention.distr.group_size
         k_f_new = kvc.fuse_new_k(k, perm, g)
         pool_k_fused = paged_insert(pool_k_fused, k_f_new, block_tables, wpos,
-                                    count)
+                                    count, layer)
         o = attend_decode(
             q, None, pool_v, cfg.attention, lengths=lengths,
             k_fused=pool_k_fused, perm=perm, group_size=g, scale=scale,
-            block_tables=block_tables,
+            block_tables=block_tables, layer=layer,
         )
         new_pools = (None, pool_v, pool_k_fused)
     else:
-        pool_k = paged_insert(pool_k, k, block_tables, wpos, count)
+        pool_k = paged_insert(pool_k, k, block_tables, wpos, count, layer)
         o = attend_decode(
             q, pool_k, pool_v, cfg.attention, lengths=lengths, scale=scale,
-            block_tables=block_tables,
+            block_tables=block_tables, layer=layer,
         )
         new_pools = (pool_k, pool_v, None)
     out = layers.linear_apply(params["wo"], _merge_heads(o.astype(x.dtype)))

@@ -25,28 +25,29 @@ def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
     attention) is excluded: the sequence dim shards over it, never the
     batch.
     """
-    try:
-        from repro.utils.jax_compat import get_abstract_mesh
-
-        mesh = get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.axis_names:
-            return x
-        from repro.distributed.sharding import CONTEXT_AXIS
-
-        dp = tuple(
-            a for a in mesh.axis_names if a not in ("model", CONTEXT_AXIS)
-        )
-        # "seq" entries denote the sequence dim: sharded over the reserved
-        # context axis when the mesh rings it, replicated otherwise.
-        ctx = CONTEXT_AXIS if CONTEXT_AXIS in mesh.axis_names else None
-        expanded = tuple(
-            (dp if s == "data" else ctx if s == "seq" else s) for s in spec
-        )
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*expanded)
-        )
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names:
         return x
+    from repro.distributed.sharding import CONTEXT_AXIS
+
+    dp = tuple(a for a in mesh.axis_names if a not in ("model", CONTEXT_AXIS))
+    # "seq" entries denote the sequence dim: sharded over the reserved
+    # context axis when the mesh rings it, replicated otherwise.
+    ctx = CONTEXT_AXIS if CONTEXT_AXIS in mesh.axis_names else None
+
+    def resolve(s):
+        if s == "data":
+            return dp
+        if s == "seq":
+            return ctx
+        # A named axis the mesh lacks (e.g. "model" on a pure context
+        # ring) replicates that dim.
+        return s if s is None or s in mesh.axis_names else None
+
+    expanded = tuple(resolve(s) for s in spec)
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*expanded)
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -43,7 +43,10 @@ def _hybrid_layout(cfg) -> tuple[int, int]:
     return n_groups, n_tail
 
 
-def init_params(key, cfg, dtype=jnp.float32):
+def init_params(key, cfg, dtype=None):
+    """Random weights in ``dtype``, by default ``cfg.param_dtype`` (f32:
+    the master copy training updates)."""
+    dtype = jnp.dtype(cfg.param_dtype if dtype is None else dtype)
     ks = iter(jax.random.split(key, 16))
     params: dict = {
         "embed": layers.embedding_init(next(ks), cfg.padded_vocab, cfg.d_model, dtype)
@@ -118,6 +121,17 @@ def init_params(key, cfg, dtype=jnp.float32):
             next(ks), cfg.d_model, cfg.padded_vocab, dtype=dtype
         )
     return params
+
+
+def init_serving_params(key, cfg):
+    """Random weights for serving: held in ``cfg.compute_dtype`` (bf16 at
+    every published width, half the bytes of the f32 training copy) and
+    built on the device in one program."""
+    return jax.jit(
+        functools.partial(
+            init_params, cfg=cfg, dtype=jnp.dtype(cfg.compute_dtype)
+        )
+    )(key)
 
 
 def param_axes(cfg):

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers
-from repro.utils.jax_compat import axis_size, get_abstract_mesh, shard_map
 
 
 def moe_init(key, cfg, dtype=jnp.float32):
@@ -133,7 +132,7 @@ def _moe_ep_a2a(params, x: jnp.ndarray, cfg, mesh):
 
     def local_moe(xl, router_w, gate_w, up_w, down_w):
         # xl: (b_loc, s_loc, D); gate/up/down: (E_loc, ·, ·) local experts.
-        ep = axis_size(axis, mesh)
+        ep = jax.lax.axis_size(axis)
         bl, sl, d = xl.shape
         t = bl * sl
         xf = xl.reshape(t, d)
@@ -210,7 +209,7 @@ def _moe_ep_a2a(params, x: jnp.ndarray, cfg, mesh):
             aux = jax.lax.pmean(aux, a)
         return y_tok.reshape(bl, sl, d).astype(xl.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(pspec_x, r_spec, e_spec, e_spec, e_spec),
@@ -239,7 +238,7 @@ def _moe_ep_psum(params, x: jnp.ndarray, cfg, mesh):
     pspec_scalar = jax.sharding.PartitionSpec()
 
     def local_moe(xl, router_w, gate_w, up_w, down_w):
-        ep = axis_size(axis, mesh)
+        ep = jax.lax.axis_size(axis)
         bl, sl, d = xl.shape
         t = bl * sl
         xf = xl.reshape(t, d)
@@ -263,7 +262,7 @@ def _moe_ep_psum(params, x: jnp.ndarray, cfg, mesh):
             aux = jax.lax.pmean(aux, a)
         return y.reshape(bl, sl, d).astype(xl.dtype), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(pspec_x, r_spec, e_spec, e_spec, e_spec),
@@ -284,13 +283,8 @@ def _moe_ep_psum(params, x: jnp.ndarray, cfg, mesh):
 
 
 def _active_mesh():
-    try:
-        mesh = get_abstract_mesh()
-        if mesh is None or mesh.empty or "model" not in mesh.axis_names:
-            return None
-        return mesh
-    except Exception:
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or "model" not in mesh.axis_names else mesh
 
 
 def moe_apply(params, x: jnp.ndarray, cfg, *, decode: bool = False):

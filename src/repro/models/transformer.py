@@ -213,16 +213,19 @@ def block_paged_decode_apply(
     count=None,
     pool_k_fused=None,
     perm=None,
+    layer=None,
 ):
     """Windowed decode of one transformer block against the paged KV pool
     (serve.paged): w = 1 is token decode, w = chunk width is chunked
     prefill.  GQA dense/moe only — the paged layout replaces the ring slab
-    cache, the other families keep the slot engine."""
+    cache, the other families keep the slot engine.  With ``layer`` the
+    pools are the model's stacks, updated in place at that layer."""
     h = norm_apply(params["norm1"], x, cfg)
     o, (pk, pv, pkf) = attn_mod.attention_decode_paged(
         params["attn"], h, cfg,
         pool_k=pool_k, pool_v=pool_v, block_tables=block_tables,
         cache_index=pos, count=count, pool_k_fused=pool_k_fused, perm=perm,
+        layer=layer,
     )
     x = x + o
     h2 = norm_apply(params["norm2"], x, cfg)

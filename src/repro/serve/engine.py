@@ -45,7 +45,7 @@ from repro.serve.lifecycle import IncompleteRun
 from repro.serve.sampler import sample
 from repro.serve.serve_step import make_decode_step, make_prefill
 from repro.tune.autotune import warm_engine
-from repro.utils.jax_compat import maybe_set_mesh
+from repro.distributed.sharding import maybe_set_mesh
 
 
 def _validate_request(prompt, limit: int, max_new_tokens: int,
@@ -626,6 +626,9 @@ class PagedServeEngine:
         self.cache = paged.PagedKVCache(
             cfg, num_blocks, self.block_size, dtype=cache_dtype
         )
+        # Decode runs Pallas kernels outside any shard_map, so the pools
+        # live on one device whatever mesh the prefill uses.
+        (self._pool_device,) = self.cache.pools["v"].devices()
         self.prefill_chunk = min(prefill_chunk, max_len)
         self.faults = faults or NULL_INJECTOR
         self.trace = trace if trace is not None else get_recorder()
@@ -637,8 +640,12 @@ class PagedServeEngine:
             degrade=degrade, faults=self.faults, trace=self.trace,
             **({"clock": clock} if clock is not None else {}),
         )
-        self._decode = jax.jit(make_paged_step(cfg, 1))
-        self._chunk = jax.jit(make_paged_step(cfg, self.prefill_chunk))
+        # The pools are donated to every step that rewrites them, so the
+        # device holds one copy of the KV (serve_step.make_paged_step).
+        self._decode = jax.jit(make_paged_step(cfg, 1), donate_argnums=(2,))
+        self._chunk = jax.jit(
+            make_paged_step(cfg, self.prefill_chunk), donate_argnums=(2,)
+        )
         self._degraded: dict[tuple[int, int], object] = {}
         self._mesh_prefills: dict = {}
         self.finished: list[Request] = []
@@ -807,7 +814,8 @@ class PagedServeEngine:
         key = (bucket, group)
         if key not in self._degraded:
             self._degraded[key] = jax.jit(
-                make_degraded_paged_prefill(self.cfg, bucket, group)
+                make_degraded_paged_prefill(self.cfg, bucket, group),
+                donate_argnums=(3,),
             )
         return self._degraded[key]
 
@@ -845,7 +853,7 @@ class PagedServeEngine:
         key = (bucket, tuple(sorted(dead)))
         if key not in self._mesh_prefills:
             self._mesh_prefills[key] = jax.jit(
-                make_mesh_paged_prefill(self.cfg, bucket)
+                make_mesh_paged_prefill(self.cfg, bucket), donate_argnums=(3,)
             )
         return self._mesh_prefills[key]
 
@@ -866,11 +874,17 @@ class PagedServeEngine:
         toks[0, :n] = entry.req.prompt
         bt = self.cache.table_array([entry.uid], self.max_blocks)
         dead = self.faults.dead_shards()
+        replicated = jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec()
+        )
         with maybe_set_mesh(self.mesh), dead_shard_fault(dead):
-            row, self.cache.pools = self._mesh_prefill_fn(bucket, dead)(
+            row, pools = self._mesh_prefill_fn(bucket, dead)(
                 self.params, jnp.asarray(toks), jnp.asarray([n], jnp.int32),
-                self.cache.pools, bt,
+                jax.device_put(self.cache.pools, replicated), bt,
             )
+        # The ring hands the pools back replicated over the mesh; the
+        # engine keeps its own device's copy.
+        self.cache.pools = jax.device_put(pools, self._pool_device)
         if self.faults.fires("nan_logits", entry.uid) is not None:
             row = jnp.full_like(row, jnp.nan)
         return row

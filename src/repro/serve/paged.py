@@ -5,7 +5,7 @@ is committed at admission for the *worst-case* sequence and short requests
 strand most of it.  The paged subsystem (vLLM's PagedAttention model) cuts
 KV into fixed-size **blocks** drawn from one shared pool:
 
-  pools:        k, v  (L, P, Hkv, block_size, dh)   [+ k_fused (·, dh/G*)]
+  pools:        k, v  (L, P, block_size, Hkv·dh)   [+ k_fused (·, Hkv·dh/G*)]
   block table:  per request, logical block j → physical pool block ids[j]
   invariant:    block 0 is a reserved GARBAGE block — never allocated, the
                 write target for dead/padded lanes so their stores can't
@@ -29,8 +29,9 @@ provides the engine-facing operations:
     never written again — only whole blocks are shared, and the dst's own
     tokens land in fresh blocks).
 
-Per-layer pool slices ride the decode scan exactly like the contiguous
-cache's ``(L, B, ...)`` stacks; block tables are shared across layers.
+The ``(L, ...)`` pool stacks ride the decode scan's carry and each layer
+writes and reads its own index in place; block tables are shared across
+layers.
 The kernel side is kernels/paged_decode.py; policy is serve/scheduler.py.
 """
 from __future__ import annotations
@@ -113,7 +114,9 @@ def pool_struct(cfg, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> di
         )
     f = jax.ShapeDtypeStruct
     l, hkv, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim_
-    pools = {"v": f((l, num_blocks, hkv, block_size, dh), dtype)}
+    # Token-major rows (kernels/paged_decode.py): all KV heads of one
+    # token share the 128 TPU lanes.
+    pools = {"v": f((l, num_blocks, block_size, hkv * dh), dtype)}
     # Mirror serve_step.make_paged_step's dispatch exactly: the fused path
     # only engages for dense — a moe config with distr_decode set still
     # runs (and pools) the raw-K path, like the slot engine's decode scan.
@@ -123,9 +126,9 @@ def pool_struct(cfg, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> di
         # cache the raw K pool is dropped entirely — an extra
         # (1 − 1/G*)·½ of the *allocation*, not just the read stream.
         g = cfg.attention.distr.group_size
-        pools["k_fused"] = f((l, num_blocks, hkv, block_size, dh // g), dtype)
+        pools["k_fused"] = f((l, num_blocks, block_size, hkv * (dh // g)), dtype)
     else:
-        pools["k"] = f((l, num_blocks, hkv, block_size, dh), dtype)
+        pools["k"] = f((l, num_blocks, block_size, hkv * dh), dtype)
     return pools
 
 
@@ -139,7 +142,7 @@ def init_pools(cfg, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> dic
 @dataclass
 class _Evicted:
     """Host copy of a preempted request's live KV (per pool key: numpy
-    (L, width, Hkv, bs, dh*) gathered blocks in logical order, possibly
+    (L, width, bs, Hkv·dh*) gathered blocks in logical order, possibly
     garbage-padded to a fixed width — see evict_to_host)."""
     length: int
     blocks: dict = field(default_factory=dict)
@@ -228,7 +231,7 @@ class PagedKVCache:
         ev = _Evicted(length=length)
         ev.n_blocks = len(table)
         for key, pool in self.pools.items():
-            # (L, width, Hkv, bs, dh*) in logical block order
+            # (L, width, bs, Hkv·dh*) in logical block order
             ev.blocks[key] = np.asarray(jnp.take(pool, idx, axis=1))
         self.evicted[uid] = ev
         for b in table:

@@ -258,59 +258,39 @@ def make_paged_step(cfg, width: int):
             x = x + layers.embedding_apply(
                 params["pos_embed"], positions, compute
             )
-        new_pools = dict(pools)
+        perms = kv_cache.static_perms(cfg) if fused else None  # (L, Hkv, dh)
 
-        if fused:
-            perms = kv_cache.static_perms(cfg)  # (L, Hkv, dh)
-
-            def body_f(h, inputs):
-                lp, v_l, kf_l, perm_l = inputs
-                h, (_, pv, pkf) = transformer.block_paged_decode_apply(
-                    lp, h, cfg, "dense",
-                    pool_k=None, pool_v=v_l, block_tables=block_tables,
-                    pos=pos, count=count, pool_k_fused=kf_l, perm=perm_l,
+        # The pools ride in the scan carry and each layer writes its window
+        # in place at its own index (the kernel reads the stack directly):
+        # with the pools donated, a step holds one copy of the KV, not two.
+        def make_body(layer_type):
+            def body(carry, inputs):
+                h, pools = carry
+                lp, layer = inputs
+                h, (pk, pv, pkf) = transformer.block_paged_decode_apply(
+                    lp, h, cfg, layer_type,
+                    pool_k=pools.get("k"), pool_v=pools["v"],
+                    block_tables=block_tables, pos=pos, count=count,
+                    pool_k_fused=pools.get("k_fused"),
+                    perm=None if perms is None else perms[layer], layer=layer,
                 )
-                return h, (pv, pkf)
+                new = {"k": pk, "v": pv, "k_fused": pkf}
+                return (h, {key: new[key] for key in pools}), None
 
-            x, (vs, kfs) = jax.lax.scan(
-                body_f, x,
-                (params["blocks"], pools["v"], pools["k_fused"], perms),
+            return body
+
+        carry = (x, dict(pools))
+        fd = cfg.first_dense_layers if cfg.family == "moe" else 0
+        if fd:
+            carry, _ = jax.lax.scan(
+                make_body("dense"), carry,
+                (params["dense_blocks"], jnp.arange(fd)),
             )
-            new_pools.update(v=vs, k_fused=kfs)
-        else:
-
-            def make_body(layer_type):
-                def body(h, inputs):
-                    lp, k_l, v_l = inputs
-                    h, (pk, pv, _) = transformer.block_paged_decode_apply(
-                        lp, h, cfg, layer_type,
-                        pool_k=k_l, pool_v=v_l, block_tables=block_tables,
-                        pos=pos, count=count,
-                    )
-                    return h, (pk, pv)
-
-                return body
-
-            if cfg.family == "moe" and cfg.first_dense_layers:
-                fd = cfg.first_dense_layers
-                x, (kd, vd) = jax.lax.scan(
-                    make_body("dense"), x,
-                    (params["dense_blocks"], pools["k"][:fd], pools["v"][:fd]),
-                )
-                x, (km, vm) = jax.lax.scan(
-                    make_body("moe"), x,
-                    (params["blocks"], pools["k"][fd:], pools["v"][fd:]),
-                )
-                new_pools["k"] = jnp.concatenate([kd, km], axis=0)
-                new_pools["v"] = jnp.concatenate([vd, vm], axis=0)
-            else:
-                layer_type = "moe" if cfg.family == "moe" else "dense"
-                x, (ks, vs) = jax.lax.scan(
-                    make_body(layer_type), x,
-                    (params["blocks"], pools["k"], pools["v"]),
-                )
-                new_pools.update(k=ks, v=vs)
-
+        layer_type = "moe" if cfg.family == "moe" else "dense"
+        (x, new_pools), _ = jax.lax.scan(
+            make_body(layer_type), carry,
+            (params["blocks"], jnp.arange(fd, cfg.n_layers)),
+        )
         x = transformer.norm_apply(params["final_norm"], x, cfg)
         logits = lm.logits_fn(params, cfg, x)
         return logits, new_pools

@@ -47,7 +47,7 @@ from repro.train import optimizer as opt_mod
 from repro.train.anomaly import AnomalyConfig, AnomalyDetector, AnomalyHalt
 from repro.train.elastic import counters_view
 from repro.train.train_step import make_train_step
-from repro.utils.jax_compat import maybe_set_mesh
+from repro.distributed.sharding import maybe_set_mesh
 
 import os
 

@@ -149,6 +149,7 @@ def _make_run_paged_decode(n, d, dtype, interpret, group_size):
     import jax.numpy as jnp
 
     from repro.kernels import ops
+    from repro.kernels.paged_decode import token_major
 
     dt = _np_dtype(dtype)
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -175,17 +176,19 @@ def _make_run_paged_decode(n, d, dtype, interpret, group_size):
             jax.random.PRNGKey(2), jnp.arange(1, p, dtype=jnp.int32)
         )[None, :]
         lengths = jnp.full((1,), n, jnp.int32)
+        v_pool = token_major(v_pool)
         if group_size > 1:
             from repro.core import grouping
 
-            k_fused = grouping.fuse_columns(
+            k_fused = token_major(grouping.fuse_columns(
                 k_pool.astype(jnp.float32), perm[None], group_size
-            ).astype(dt)
+            ).astype(dt))
             return lambda: ops.paged_decode_attention(
                 q, None, v_pool, block_tables=bt, lengths=lengths,
                 k_fused_pool=k_fused, perm=perm, group_size=group_size,
                 interpret=interpret,
             )
+        k_pool = token_major(k_pool)
         return lambda: ops.paged_decode_attention(
             q, k_pool, v_pool, block_tables=bt, lengths=lengths,
             interpret=interpret,
@@ -406,7 +409,6 @@ def _make_run_distr_bwd(n, d, dtype, causal, interpret, group_size, block_q,
     kf = k.reshape(-1, n, d)
     vf = v.reshape(-1, n, d)
     perm_f = perms.reshape(1, -1, d)
-    inv_perm_f = jnp.argsort(perm_f, axis=-1).astype(perm_f.dtype)
     delta = bwd.delta_kernel_call(
         of, dof, block_q=cfg.block_q, interpret=interpret
     )
@@ -425,7 +427,7 @@ def _make_run_distr_bwd(n, d, dtype, causal, interpret, group_size, block_q,
             ))
         else:
             fn = jax.jit(lambda: bwd.distr_dkv_kernel_call(
-                q_hat, kp, vp, perm_f, inv_perm_f, dof, lse, delta, **kw
+                q_hat, kp, vp, perm_f, dof, lse, delta, **kw
             ))
         return fn
 
@@ -437,6 +439,7 @@ def _make_run_decode(n, d, dtype, interpret, group_size):
     import jax.numpy as jnp
 
     from repro.kernels import ops
+    from repro.kernels.paged_decode import token_major
 
     dt = _np_dtype(dtype)
     ks = jax.random.split(jax.random.PRNGKey(0), 3)

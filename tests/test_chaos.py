@@ -788,11 +788,11 @@ def test_dead_ring_shard_degraded_but_finite_8dev():
         sys.path.insert(0, %r)
         import jax, jax.numpy as jnp
         import numpy as np
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.distributed.ring_attention import (
             dead_shard_fault, ring_flash_attention,
         )
-        ring = compat_make_mesh((8,), ("context",))
+        ring = make_mesh((8,), ("context",))
         B, Hq, Hkv, N, D = 1, 2, 1, 1024, 32  # 8 shards of 128, all live
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (B, Hq, N, D), jnp.float32)

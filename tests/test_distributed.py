@@ -25,9 +25,10 @@ def _run_subprocess(body: str):
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.launch.mesh import compat_make_mesh
-        from repro.utils.jax_compat import get_abstract_mesh, set_mesh, shard_map
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        from jax import shard_map
+        from jax.sharding import get_abstract_mesh, set_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         """
         % SRC
     ) + textwrap.dedent(body)
@@ -47,9 +48,9 @@ def _run_subprocess(body: str):
 def test_param_specs_rules():
     import jax.numpy as jnp
 
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     axes = {"w": (None, "mlp"), "e": ("experts", None, None), "s": (None,)}
     shapes = {
         "w": jax.ShapeDtypeStruct((4096, 8192), jnp.float32),
@@ -249,7 +250,7 @@ def test_pipeline_matches_sequential():
     _run_subprocess(
         """
         from repro.distributed.pipeline import pipeline_apply, stage_split
-        mesh2 = compat_make_mesh((4, 2), ("pod", "model"))
+        mesh2 = make_mesh((4, 2), ("pod", "model"))
         L, D = 8, 16
         ws = jax.random.normal(jax.random.PRNGKey(0), (L, D, D)) * 0.2
 
@@ -302,7 +303,7 @@ def test_ring_flash_parity_8dev():
         """
         from repro.distributed.ring_attention import ring_flash_attention
         from repro.kernels import ops
-        ring = compat_make_mesh((8,), ("context",))
+        ring = make_mesh((8,), ("context",))
         B, Hq, Hkv, N, D = 2, 4, 2, 300, 64  # ragged: 3 live shards of 128
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         qf = jax.random.normal(ks[0], (B, Hq, N, D), jnp.float32)
@@ -351,7 +352,7 @@ def test_ring_distr_parity_8dev():
         from repro.core.distr_attention import DistrConfig
         from repro.distributed.ring_attention import ring_distr_attention
         from repro.kernels import ops
-        ring = compat_make_mesh((8,), ("context",))
+        ring = make_mesh((8,), ("context",))
         B, Hq, Hkv, N, D = 2, 4, 2, 300, 64
         dcfg = DistrConfig(group_size=2)  # block_q=128: the grouping grain
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -402,7 +403,7 @@ def test_ring_distr_shared_kv_perm_parity_8dev():
         from repro.core.distr_attention import DistrConfig
         from repro.distributed.ring_attention import ring_distr_attention
         from repro.kernels import ops
-        ring = compat_make_mesh((8,), ("context",))
+        ring = make_mesh((8,), ("context",))
         B, Hq, Hkv, N, D = 2, 4, 2, 300, 64
         dcfg = DistrConfig(group_size=2, shared_kv_perm=True)
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -444,7 +445,7 @@ def test_attend_context_axis_dispatch_8dev():
     _run_subprocess(
         """
         from repro.core import attend, AttentionConfig, DistrConfig
-        ring = compat_make_mesh((2, 2, 2), ("data", "context", "model"))
+        ring = make_mesh((2, 2, 2), ("data", "context", "model"))
         B, Hq, Hkv, N, D = 2, 4, 2, 512, 32
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (B, Hq, N, D), jnp.float32)
@@ -481,7 +482,7 @@ def test_pipeline_drain_ticks_inject_zeros():
         """
         from collections import Counter
         from repro.distributed.pipeline import pipeline_apply
-        mesh2 = compat_make_mesh((4, 1), ("pod", "model"))
+        mesh2 = make_mesh((4, 1), ("pod", "model"))
         S, M, MB, D = 4, 6, 2, 8
         seen = []
         def record(stage, val):
@@ -524,7 +525,7 @@ def test_serve_engine_ring_prefill_matches_single_device():
         cfg = cfg.replace(attention=dc_replace(
             cfg.attention, impl="pallas_flash", context_axis="context"))
         params = lm.init_params(jax.random.PRNGKey(0), cfg)
-        ring = compat_make_mesh((2,), ("context",))
+        ring = make_mesh((2,), ("context",))
         prompt = list(np.random.RandomState(0).randint(
             0, cfg.vocab, size=300))  # bucket 512 ≥ ring×128 → ring prefill
 
@@ -564,7 +565,7 @@ def test_paged_engine_mesh_prefill_matches_single_device():
         cfg = cfg.replace(attention=dc_replace(
             cfg.attention, impl="pallas_flash", context_axis="context"))
         params = lm.init_params(jax.random.PRNGKey(0), cfg)
-        ring = compat_make_mesh((2,), ("context",))
+        ring = make_mesh((2,), ("context",))
         prompt = list(np.random.RandomState(0).randint(
             0, cfg.vocab, size=300))  # bucket 512 ≥ ring×128 → ring prefill
 
@@ -633,7 +634,7 @@ def test_context_parallel_train_step_matches_single_device():
         cfg_cp = cfg.replace(attention=dc_replace(
             cfg.attention, context_axis="context"))
         step_cp = make_train_step(cfg_cp, opt_cfg)
-        mesh_cp = compat_make_mesh((2, 4), ("data", "context"))
+        mesh_cp = make_mesh((2, 4), ("data", "context"))
         with set_mesh(mesh_cp):
             p2, _, m2 = jax.jit(step_cp)(
                 params, jax.tree_util.tree_map(jnp.asarray, opt), batch,

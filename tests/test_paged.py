@@ -8,6 +8,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import grouping
 from repro.core.api import attend_decode
+from repro.kernels.paged_decode import token_major
 from repro.models import lm
 from repro.serve import paged
 from repro.serve.engine import PagedServeEngine
@@ -16,7 +17,8 @@ from repro.serve.serve_step import make_decode_step, make_paged_step, make_prefi
 
 
 def _random_pool_case(key, b, hkv, d, bs, mb, dtype=jnp.float32):
-    """Pools + a shuffled (non-contiguous) block table per request."""
+    """Token-major pools + a shuffled (non-contiguous) block table per
+    request."""
     ks = jax.random.split(key, 3)
     p = 1 + b * mb  # + reserved garbage block 0
     k_pool = jax.random.normal(ks[0], (p, hkv, bs, d), jnp.float32).astype(dtype)
@@ -24,13 +26,13 @@ def _random_pool_case(key, b, hkv, d, bs, mb, dtype=jnp.float32):
     ids = np.arange(1, p, dtype=np.int32)
     np.random.RandomState(0).shuffle(ids)
     bt = jnp.asarray(ids.reshape(b, mb))
-    return k_pool, v_pool, bt, ks[2]
+    return token_major(k_pool), token_major(v_pool), bt, ks[2]
 
 
-def _gather(pool, bt):
-    g = jnp.take(pool, bt, axis=0)  # (B, mb, Hkv, bs, d)
-    b, mb, hkv, bs, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, mb * bs, d)
+def _gather(pool, bt, hkv):
+    g = jnp.take(pool, bt, axis=0)  # (B, mb, bs, Hkv·d)
+    b, mb, bs, width = g.shape
+    return g.reshape(b, mb * bs, hkv, width // hkv).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -57,8 +59,8 @@ def test_paged_kernel_matches_gathered_oracle(dtype, q_per_kv):
     )
     want = ref.decode_attention_ref(
         q.astype(jnp.float32),
-        _gather(k_pool, bt).astype(jnp.float32),
-        _gather(v_pool, bt).astype(jnp.float32),
+        _gather(k_pool, bt, hkv).astype(jnp.float32),
+        _gather(v_pool, bt, hkv).astype(jnp.float32),
         lengths,
     )
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -83,7 +85,7 @@ def test_paged_kernel_banded_window():
     )
     from repro.core.flash_reference import reference_attention
 
-    k_c, v_c = _gather(k_pool, bt), _gather(v_pool, bt)
+    k_c, v_c = _gather(k_pool, bt, hkv), _gather(v_pool, bt, hkv)
     for bi in range(b):
         for i in range(ql):
             mask = (
@@ -118,7 +120,7 @@ def test_paged_kernel_window_overhanging_capacity():
     out = ops.paged_decode_attention(
         q, k_pool, v_pool, block_tables=bt, lengths=lengths
     )
-    k_c, v_c = _gather(k_pool, bt), _gather(v_pool, bt)
+    k_c, v_c = _gather(k_pool, bt, hkv), _gather(v_pool, bt, hkv)
     for t in range(live):
         mask = jnp.arange(mb * bs)[None, :] < (pos + t + 1)  # own band
         want = reference_attention(
@@ -142,7 +144,8 @@ def test_paged_kernel_fused_variant():
         jax.random.permutation(jax.random.fold_in(ks[2], h), d)
         for h in range(hkv)
     ]).astype(jnp.int32)
-    kf_pool = grouping.fuse_columns(k_pool, perm[None], g)
+    kf_pool = token_major(grouping.fuse_columns(k_pool, perm[None], g))
+    v_pool = token_major(v_pool)
     ids = np.arange(1, p, dtype=np.int32)
     np.random.RandomState(1).shuffle(ids)
     bt = jnp.asarray(ids.reshape(b, mb))
@@ -606,3 +609,23 @@ def test_paged_engine_decode_crosses_capacity():
     assert req.uid == uid
     assert len(req.generated) == 20
     assert eng.cache.pool.num_free == eng.cache.pool.num_blocks - 1
+
+
+def test_paged_chunk_overhanging_capacity_matches_small_chunks():
+    """Regression: the last chunked-prefill window of a prompt may overhang
+    the table capacity with padded rows (here a 44-token tail in a 256-wide
+    window at position 256, capacity 256 + 128).  Its live rows keep their
+    full causal band, so greedy tokens equal those of small chunks that
+    never overhang."""
+    cfg = get_config("minicpm-2b", reduced=True)
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    prompt = np.random.RandomState(0).randint(1, cfg.vocab, size=300).tolist()
+    out = []
+    for chunk in (256, 32):
+        eng = PagedServeEngine(cfg, params, max_batch=1, max_len=364,
+                               block_size=128, prefill_chunk=chunk,
+                               cache_dtype=jnp.float32)
+        assert eng.capacity_tokens == 384
+        eng.add_request(prompt, max_new_tokens=3)
+        out.append(eng.run_to_completion()[0].generated)
+    assert out[0] == out[1], out
