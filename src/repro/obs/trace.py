@@ -27,6 +27,21 @@ Export is :meth:`to_chrome` — ``{"traceEvents": [...]}`` loadable directly
 in Perfetto / ``chrome://tracing``; still-open spans export as ``"B"``
 events so nothing in flight is hidden.
 
+**The profiler's clock.**  While a sync span is open it also holds a
+``jax.profiler.TraceAnnotation`` of the same name, its args as the
+annotation's kwargs (scalars only), and an instant is a zero-length
+annotation.  The profiler keeps them only while a trace is active, so a
+recorder installed for a whole run puts its spans into the ``.xplane.pb``
+on the profiler's host clock, next to the device's events: the benchmark
+charges the device's idle time to them (``bench/program_spans.py``).
+``jax`` is imported on the first span, so importing ``repro.obs`` does not.
+
+**Compile events.**  Installing a real recorder (:func:`set_recorder` /
+:func:`use_recorder`) registers, once per process, a ``jax.monitoring``
+listener on JAX's backend compiles: each records an instant ``compile``
+(``fun_name``, ``seconds``) on whatever recorder is current when it fires,
+so a trace says which step compiled, and when.
+
 The process-global recorder (:func:`get_recorder` / :func:`set_recorder`,
 default :data:`NULL_RECORDER`) is how layers without a constructor
 parameter path (the autotuner's measurement sweeps) emit: ``--trace`` on
@@ -47,12 +62,22 @@ from repro.obs.clock import resolve_clock
 #: Default ring-buffer capacity (completed events).
 DEFAULT_MAXLEN = 65536
 
+#: JAX's ``jax.monitoring`` event for one backend compile.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _annotation(name: str, args: dict):
+    """A ``jax.profiler.TraceAnnotation`` for a span or instant."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **args)
+
 
 class _Span:
     """Re-entrant handle for one open sync span (lives on the recorder's
     open stack, never in the ring buffer, until it closes)."""
 
-    __slots__ = ("rec", "name", "args", "tid", "t0")
+    __slots__ = ("rec", "name", "args", "tid", "t0", "ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, tid: int, args: dict):
         self.rec = rec
@@ -60,14 +85,18 @@ class _Span:
         self.tid = tid
         self.args = args
         self.t0 = 0.0
+        self.ann = None
 
     def __enter__(self):
+        self.ann = _annotation(self.name, self.args)
+        self.ann.__enter__()
         self.t0 = self.rec.clock()
         self.rec._open.append(self)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.rec._open.remove(self)
+        self.ann.__exit__(exc_type, exc, tb)
         self.rec._push({
             "name": self.name, "ph": "X", "t": self.t0,
             "dur": self.rec.clock() - self.t0,
@@ -127,6 +156,8 @@ class TraceRecorder:
 
     def instant(self, name: str, *, tid: int = 0, **args) -> None:
         """Point event (Chrome ``"i"``, thread scope)."""
+        with _annotation(name, args):
+            pass
         self._push({"name": name, "ph": "i", "t": self.clock(),
                     "tid": tid, "args": args})
 
@@ -228,9 +259,27 @@ def get_recorder():
     return _current
 
 
+_compile_listener = False
+
+
+def _on_compile(event: str, start: float, end: float, **kw) -> None:
+    if event == COMPILE_EVENT:
+        _current.instant("compile", fun_name=kw.get("fun_name", ""),
+                         seconds=end - start)
+
+
 def set_recorder(rec) -> None:
-    global _current
+    """Make ``rec`` current (None: the NullRecorder).  The first real
+    recorder also registers the compile listener: ``jax.monitoring`` keeps
+    listeners for the life of the process, so it is registered once and
+    reads the current recorder when it fires."""
+    global _current, _compile_listener
     _current = rec if rec is not None else NULL_RECORDER
+    if _current.enabled and not _compile_listener:
+        import jax
+
+        jax.monitoring.register_event_time_span_listener(_on_compile)
+        _compile_listener = True
 
 
 @contextmanager
@@ -238,7 +287,7 @@ def use_recorder(rec):
     """Scoped global-recorder install (tests; benchmark runs)."""
     global _current
     prev = _current
-    _current = rec if rec is not None else NULL_RECORDER
+    set_recorder(rec)
     try:
         yield rec
     finally:

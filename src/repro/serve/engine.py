@@ -796,14 +796,18 @@ class PagedServeEngine:
         # Raised BEFORE any pool mutation: a retried chunk re-runs cleanly.
         self.faults.raise_if("stuck_step", entry.uid)
         start = entry.prompt_done
-        toks = np.zeros((1, self.prefill_chunk), np.int32)
-        toks[0, :chunk] = entry.req.prompt[start : start + chunk]
-        bt = self.cache.table_array([entry.uid], self.max_blocks)
-        logits, self.cache.pools = self._chunk(
-            self.params, jnp.asarray(toks), self.cache.pools, bt,
-            jnp.asarray([start], jnp.int32), jnp.asarray([chunk], jnp.int32),
-        )
-        row = logits[0, chunk - 1]
+        with self.trace.span("serve.chunk.inputs"):
+            toks = np.zeros((1, self.prefill_chunk), np.int32)
+            toks[0, :chunk] = entry.req.prompt[start : start + chunk]
+            bt = self.cache.table_array([entry.uid], self.max_blocks)
+            toks_d = jnp.asarray(toks)
+            start_d = jnp.asarray([start], jnp.int32)
+            n_d = jnp.asarray([chunk], jnp.int32)
+        with self.trace.span("serve.chunk.launch"):
+            logits, self.cache.pools = self._chunk(
+                self.params, toks_d, self.cache.pools, bt, start_d, n_d,
+            )
+            row = logits[0, chunk - 1]
         if self.faults.fires("nan_logits", entry.uid) is not None:
             row = jnp.full_like(row, jnp.nan)
         return row
@@ -894,36 +898,47 @@ class PagedServeEngine:
         ``(tokens, ok)`` — (max_batch,) sampled tokens (garbage on idle
         lanes — the scheduler only reads occupied ones) and the numeric
         health mask (False = that lane's logits went non-finite; the
-        scheduler quarantines exactly that request)."""
+        scheduler quarantines exactly that request).
+
+        Spans: ``serve.decode.inputs`` (lane arrays, block table, host→
+        device copies), ``serve.decode.launch`` (the jitted step),
+        ``serve.decode.health`` (the finite-logits pull, the first wait on
+        the device) and ``serve.decode.sample`` (sampling and the token
+        pull)."""
         for e in running.values():
             # Raised BEFORE the model call (no pool mutated): the retried
             # tick re-runs cleanly and only the culprit spends budget.
             self.faults.raise_if("stuck_step", e.uid)
-        occupied = np.zeros((self.max_batch,), bool)
-        pos = np.zeros((self.max_batch,), np.int32)
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        uids = [-1] * self.max_batch
-        for lane, e in running.items():
-            occupied[lane] = True
-            pos[lane] = e.length
-            toks[lane, 0] = e.next_token
-            uids[lane] = e.uid
-        bt = self.cache.table_array(uids, self.max_blocks)
-        count = jnp.asarray(occupied.astype(np.int32))
-        logits, self.cache.pools = self._decode(
-            self.params, jnp.asarray(toks), self.cache.pools, bt,
-            jnp.asarray(pos), count,
-        )
-        nan_lanes = [lane for lane, e in running.items()
-                     if self.faults.fires("nan_logits", e.uid) is not None]
-        if nan_lanes:
-            logits = logits.at[np.array(sorted(nan_lanes)), -1].set(jnp.nan)
-        # Numeric health guard: one device-side reduce, one tiny transfer.
-        # Only occupied lanes count (idle lanes decode garbage by design).
-        ok = np.asarray(jnp.isfinite(logits[:, -1]).all(axis=-1)) | ~occupied
-        self._rng, sub = jax.random.split(self._rng)
-        next_tokens = sample(
-            logits[:, -1], rng=sub, temperature=self.temperature,
-            top_k=self.top_k, top_p=self.top_p,
-        )
-        return np.asarray(next_tokens), ok
+        with self.trace.span("serve.decode.inputs"):
+            occupied = np.zeros((self.max_batch,), bool)
+            pos = np.zeros((self.max_batch,), np.int32)
+            toks = np.zeros((self.max_batch, 1), np.int32)
+            uids = [-1] * self.max_batch
+            for lane, e in running.items():
+                occupied[lane] = True
+                pos[lane] = e.length
+                toks[lane, 0] = e.next_token
+                uids[lane] = e.uid
+            bt = self.cache.table_array(uids, self.max_blocks)
+            toks_d, pos_d = jnp.asarray(toks), jnp.asarray(pos)
+            count = jnp.asarray(occupied.astype(np.int32))
+        with self.trace.span("serve.decode.launch"):
+            logits, self.cache.pools = self._decode(
+                self.params, toks_d, self.cache.pools, bt, pos_d, count,
+            )
+        with self.trace.span("serve.decode.health"):
+            nan_lanes = [lane for lane, e in running.items()
+                         if self.faults.fires("nan_logits", e.uid) is not None]
+            if nan_lanes:
+                logits = logits.at[np.array(sorted(nan_lanes)), -1].set(jnp.nan)
+            # Numeric health guard: one device-side reduce, one tiny
+            # transfer.  Only occupied lanes count (idle lanes decode
+            # garbage by design).
+            ok = np.asarray(jnp.isfinite(logits[:, -1]).all(axis=-1)) | ~occupied
+        with self.trace.span("serve.decode.sample"):
+            self._rng, sub = jax.random.split(self._rng)
+            next_tokens = sample(
+                logits[:, -1], rng=sub, temperature=self.temperature,
+                top_k=self.top_k, top_p=self.top_p,
+            )
+            return np.asarray(next_tokens), ok

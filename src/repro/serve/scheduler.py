@@ -348,7 +348,8 @@ class Scheduler:
         if not cands:
             return False
         victim = max(cands, key=lambda e: e.uid)
-        engine.evict(victim)
+        with self.trace.span("serve.kv.evict", uid=victim.uid):
+            engine.evict(victim)
         victim.evicted = True
         victim.req.status = lifecycle.PREEMPTED
         victim.metrics.n_preemptions += 1
@@ -379,31 +380,33 @@ class Scheduler:
                        finished: list) -> None:
         """Prompt fully prefilled: health-check the last-position logits,
         sample the first token, and either finish (max_new_tokens=1 / eos)
-        or move to a decode lane."""
-        row = np.asarray(logits_row, np.float32)
-        if not np.isfinite(row).all():
-            # Numeric quarantine: a non-finite distribution poisons only
-            # this request — its blocks free now, the batch keeps running.
-            self._fail(head, engine, "failed_numeric", finished)
-            return
-        tok = engine.sample_one(logits_row)
-        head.req.generated.append(tok)
-        head.next_token = tok
-        head.metrics.t_first_token = self._now()
-        self.trace.instant("first_token", uid=head.uid)
-        # The first token may already satisfy the stop conditions
-        # (max_new_tokens=1 / eos): finish without a decode tick —
-        # the slot engine's contract, and one saved decode.
-        if (len(head.req.generated) >= head.req.max_new_tokens
-                or (head.req.eos_id is not None
-                    and tok == head.req.eos_id)):
-            head.req.done = True
-            self._finalize(head, engine, lifecycle.DONE)
-            finished.append(head.req)
-            return
-        head.req.status = lifecycle.RUNNING
-        head.lane = engine.free_lane()
-        self.running[head.lane] = head
+        or move to a decode lane.  ``serve.first_token`` covers the logits
+        pull, where the host waits for the final chunk."""
+        with self.trace.span("serve.first_token", uid=head.uid):
+            row = np.asarray(logits_row, np.float32)
+            if not np.isfinite(row).all():
+                # Numeric quarantine: a non-finite distribution poisons only
+                # this request — its blocks free now, the batch keeps running.
+                self._fail(head, engine, "failed_numeric", finished)
+                return
+            tok = engine.sample_one(logits_row)
+            head.req.generated.append(tok)
+            head.next_token = tok
+            head.metrics.t_first_token = self._now()
+            self.trace.instant("first_token", uid=head.uid)
+            # The first token may already satisfy the stop conditions
+            # (max_new_tokens=1 / eos): finish without a decode tick —
+            # the slot engine's contract, and one saved decode.
+            if (len(head.req.generated) >= head.req.max_new_tokens
+                    or (head.req.eos_id is not None
+                        and tok == head.req.eos_id)):
+                head.req.done = True
+                self._finalize(head, engine, lifecycle.DONE)
+                finished.append(head.req)
+                return
+            head.req.status = lifecycle.RUNNING
+            head.lane = engine.free_lane()
+            self.running[head.lane] = head
 
     def _step_fault(self, engine, e: Entry, finished: list) -> bool:
         """Bounded retry for a faulting model step.  Returns True when the
@@ -420,36 +423,54 @@ class Scheduler:
     def tick(self, engine) -> list:
         """One scheduling step.  Returns newly *terminal* Requests — done,
         expired, cancelled-by-deadline, or failed this tick (rejected and
-        explicitly cancelled requests terminate inside submit()/cancel())."""
-        self._tick += 1
-        finished: list = []
+        explicitly cancelled requests terminate inside submit()/cancel()).
+
+        Each phase runs under a span (``serve.tick`` around them all), so
+        a trace charges the host's time, and the device's idle time, to
+        the phase that spent it."""
+        with self.trace.span("serve.tick"):
+            self._tick += 1
+            finished: list = []
+
+            # A straggling step: the injected delay ages every in-flight
+            # deadline before the sweep below.
+            spec = self.faults.fires("slow_step")
+            if spec is not None:
+                self._clock_offset += spec.delay
+
+            with self.trace.span("serve.expire"):
+                progressed = self._expire_pass(engine, finished)
+
+            if self.degrade is not None:
+                self._level = self.degrade.observe(
+                    len(self.waiting), self._ttft_p50()
+                )
+                if self._level != self._last_level:
+                    self.trace.instant("degrade_level", level=self._level)
+                    self._last_level = self._level
+
+            # The decode phase is reserved first.
+            budget = self.cfg.budget() - len(self.running)
+            with self.trace.span("serve.admit"):
+                progressed |= self._admit_pass(engine, budget, finished)
+            if self.running:
+                with self.trace.span("serve.grow"):
+                    self._grow_pass(engine)
+            if self.running:
+                progressed |= self._decode_pass(engine, finished)
+            self._watchdog(engine, progressed, finished)
+            return finished
+
+    def _admit_pass(self, engine, budget: int, finished: list) -> bool:
+        """Admission and chunked prefill, FCFS from the head of the queue,
+        within ``budget`` tokens.  Returns whether anything progressed.
+
+        The head is POPPED before any allocation: preemption pushes
+        victims onto the queue front mid-allocation, so indexing the
+        queue while holding the head would pop the wrong entry.  Any
+        path that leaves the head unfinished puts it back in front
+        (it is the oldest entry, so FCFS order is preserved)."""
         progressed = False
-
-        # A straggling step: the injected delay ages every in-flight
-        # deadline before the sweep below.
-        spec = self.faults.fires("slow_step")
-        if spec is not None:
-            self._clock_offset += spec.delay
-
-        progressed |= self._expire_pass(engine, finished)
-
-        if self.degrade is not None:
-            self._level = self.degrade.observe(
-                len(self.waiting), self._ttft_p50()
-            )
-            if self._level != self._last_level:
-                self.trace.instant("degrade_level", level=self._level)
-                self._last_level = self._level
-
-        budget = self.cfg.budget()
-        budget -= len(self.running)  # decode phase reserved first
-
-        # ---- admission / chunked prefill (FCFS head of queue) ----------
-        # The head is POPPED before any allocation: preemption pushes
-        # victims onto the queue front mid-allocation, so indexing the
-        # queue while holding the head would pop the wrong entry.  Any
-        # path that leaves the head unfinished puts it back in front
-        # (it is the oldest entry, so FCFS order is preserved).
         while (budget > 0 and self.waiting
                and len(self.running) < self.cfg.max_batch):
             head = self.waiting.popleft()
@@ -465,7 +486,8 @@ class Scheduler:
                 # _alloc_or_preempt).  Until then the head waits; running
                 # lanes keep finishing and freeing.
                 try:
-                    restored = engine.restore(head)
+                    with self.trace.span("serve.kv.restore", uid=head.uid):
+                        restored = engine.restore(head)
                 except InjectedFault:
                     # A raise is a FAULT (host↔device copy failure) and
                     # spends retry budget; a False return is a capacity
@@ -585,7 +607,9 @@ class Scheduler:
                 break
             head.req.status = lifecycle.PREFILL
             try:
-                logits_last = engine.prefill_chunk_run(head, chunk)
+                with self.trace.span("serve.prefill_chunk", uid=head.uid,
+                                     start=head.prompt_done, n=chunk):
+                    logits_last = engine.prefill_chunk_run(head, chunk)
             except InjectedFault:
                 if self._step_fault(engine, head, finished):
                     progressed = True
@@ -605,90 +629,90 @@ class Scheduler:
                 # Partial prefill: back to the front; the loop (or the
                 # next tick) continues this prompt's chunks first.
                 self.waiting.appendleft(head)
+        return progressed
 
-        # ---- decode tick over all running lanes ------------------------
-        if self.running:
-            # Decode writes one token at position `length` per lane: make
-            # sure every lane's table covers it (preempting if needed).
-            for lane in sorted(self.running):
-                e = self.running.get(lane)
-                if e is None:
+    def _grow_pass(self, engine) -> None:
+        """Decode writes one token at position `length` per lane: make
+        sure every lane's table covers it (preempting if needed)."""
+        for lane in sorted(self.running):
+            e = self.running.get(lane)
+            if e is None:
+                continue
+            if not self._alloc_or_preempt(engine, e, e.length + 1):
+                if e.evicted:
+                    # The grower was the newest holder and self-
+                    # preempted (LIFO): it decodes after a restore.
                     continue
-                if not self._alloc_or_preempt(engine, e, e.length + 1):
-                    if e.evicted:
-                        # The grower was the newest holder and self-
-                        # preempted (LIFO): it decodes after a restore.
-                        continue
-                    # Oldest request alone can't grow: capacity bug — the
-                    # constructor guarantees one full request fits.
-                    raise RuntimeError(
-                        f"request {e.uid} cannot grow to {e.length + 1} "
-                        "tokens with an empty pool"
-                    )
-            if self.running:
-                try:
-                    with self.trace.span("decode", n_lanes=len(self.running)):
-                        out = engine.decode_tick(self.running)
-                    # Engines return (tokens, ok_mask); legacy fakes
-                    # returning bare tokens get an all-healthy mask.
-                    if isinstance(out, tuple):
-                        toks, ok = out
-                    else:
-                        toks, ok = out, np.ones((len(out),), bool)
-                except InjectedFault as f:
-                    # The whole batched step is lost (nothing was written:
-                    # engines raise before mutating pools) but only the
-                    # culprit spends retry budget; everyone else just
-                    # loses one tick, bounded by step_max_retries.
-                    culprit = next(
-                        (x for x in self.running.values()
-                         if x.uid == f.uid), None,
-                    )
-                    if culprit is not None and self._step_fault(
-                            engine, culprit, finished):
-                        progressed = True
-                else:
-                    now = self._now()
-                    for lane, e in list(self.running.items()):
-                        if not ok[lane]:
-                            # Numeric quarantine: only the offending lane
-                            # dies; the other lanes' KV and tokens are
-                            # untouched (per-row independence).
-                            self._fail(e, engine, "failed_numeric",
-                                       finished)
-                            progressed = True
-                            continue
-                        e.step_tries = 0
-                        t = int(toks[lane])
-                        e.req.generated.append(t)
-                        e.next_token = t
-                        e.length += 1
-                        progressed = True
-                        limit = (len(e.req.generated)
-                                 >= e.req.max_new_tokens)
-                        hit_eos = (
-                            e.req.eos_id is not None and t == e.req.eos_id
-                        )
-                        # Window-decoding engines slide past the table
-                        # bound (head-block recycling) — only engines
-                        # without the ring-write invariant force-finish
-                        # at capacity.
-                        full = (
-                            not getattr(engine, "window_decode", False)
-                            and e.length >= engine.capacity_tokens - 1
-                        )
-                        if limit or hit_eos or full:
-                            e.req.done = True
-                            self._finalize(e, engine, lifecycle.DONE)
-                            finished.append(e.req)
+                # Oldest request alone can't grow: capacity bug — the
+                # constructor guarantees one full request fits.
+                raise RuntimeError(
+                    f"request {e.uid} cannot grow to {e.length + 1} "
+                    "tokens with an empty pool"
+                )
 
-        # ---- global-stall watchdog -------------------------------------
-        # Per-entry no-progress timers would shoot legitimately queued
-        # requests under overload; the global form only fires when NOTHING
-        # moved — a wedged allocator / dead engine — and then fails the
-        # FCFS head (the entry the whole queue is stuck behind).  Failing
-        # it is itself progress, so the counter resets and termination
-        # stays bounded.
+    def _decode_pass(self, engine, finished: list) -> bool:
+        """One batched decode tick over all running lanes, then each lane's
+        token appended (``serve.emit``).  Returns whether anything
+        progressed."""
+        try:
+            with self.trace.span("serve.decode_step",
+                                 n_lanes=len(self.running)):
+                out = engine.decode_tick(self.running)
+            # Engines return (tokens, ok_mask); legacy fakes returning
+            # bare tokens get an all-healthy mask.
+            if isinstance(out, tuple):
+                toks, ok = out
+            else:
+                toks, ok = out, np.ones((len(out),), bool)
+        except InjectedFault as f:
+            # The whole batched step is lost (nothing was written: engines
+            # raise before mutating pools) but only the culprit spends
+            # retry budget; everyone else just loses one tick, bounded by
+            # step_max_retries.
+            culprit = next(
+                (x for x in self.running.values() if x.uid == f.uid), None,
+            )
+            return culprit is not None and self._step_fault(
+                engine, culprit, finished)
+        progressed = False
+        now = self._now()
+        with self.trace.span("serve.emit"):
+            for lane, e in list(self.running.items()):
+                if not ok[lane]:
+                    # Numeric quarantine: only the offending lane dies;
+                    # the other lanes' KV and tokens are untouched
+                    # (per-row independence).
+                    self._fail(e, engine, "failed_numeric", finished)
+                    progressed = True
+                    continue
+                e.step_tries = 0
+                t = int(toks[lane])
+                e.req.generated.append(t)
+                e.next_token = t
+                e.length += 1
+                progressed = True
+                limit = len(e.req.generated) >= e.req.max_new_tokens
+                hit_eos = e.req.eos_id is not None and t == e.req.eos_id
+                # Window-decoding engines slide past the table bound
+                # (head-block recycling) — only engines without the
+                # ring-write invariant force-finish at capacity.
+                full = (
+                    not getattr(engine, "window_decode", False)
+                    and e.length >= engine.capacity_tokens - 1
+                )
+                if limit or hit_eos or full:
+                    e.req.done = True
+                    self._finalize(e, engine, lifecycle.DONE)
+                    finished.append(e.req)
+        return progressed
+
+    def _watchdog(self, engine, progressed: bool, finished: list) -> None:
+        """Global-stall watchdog.  Per-entry no-progress timers would shoot
+        legitimately queued requests under overload; the global form only
+        fires when NOTHING moved — a wedged allocator / dead engine — and
+        then fails the FCFS head (the entry the whole queue is stuck
+        behind).  Failing it is itself progress, so the counter resets and
+        termination stays bounded."""
         if progressed or not self.has_work():
             self._stall_ticks = 0
         else:
@@ -701,4 +725,3 @@ class Scheduler:
                 self.trace.instant("watchdog", uid=victim.uid)
                 self._fail(victim, engine, "watchdog_fails", finished)
                 self._stall_ticks = 0
-        return finished

@@ -17,6 +17,9 @@ The contracts under test (DESIGN.md §Observability):
     unconditional costs nothing measurable.
   * clock audit — no serve/train module reads wall time directly; every
     time read flows through the injectable obs.clock discipline.
+  * the profiler's clock — a recorder's spans and instants reach an active
+    ``jax.profiler`` trace with their args, and JAX's compiles become
+    ``compile`` instants on the installed recorder.
 """
 import json
 import os
@@ -249,6 +252,79 @@ def test_null_recorder_overhead_unmeasurable():
     assert per_call_us < 25.0, (
         f"NullRecorder costs {per_call_us:.2f}us/call — no longer free"
     )
+
+
+def _host_events(trace_dir):
+    """Host events of the one ``.xplane.pb`` written under ``trace_dir``,
+    by name."""
+    from jax.profiler import ProfileData
+
+    (path,) = trace_dir.glob("**/*.xplane.pb")
+    return {e.name: e for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def test_spans_and_instants_reach_the_profiler_with_their_args(tmp_path):
+    """Under an active ``jax.profiler`` trace a recorder's spans and
+    instants come back as host events of the same names, with their args
+    and the spans' nesting, while the ring buffer records as before."""
+    import jax
+
+    rec = TraceRecorder(clock=StepClock())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("serve.tick"):
+            with rec.span("serve.prefill_chunk", uid=3, start=256, n=128):
+                pass
+            rec.instant("compile", fun_name="jit_step", seconds=0.5)
+    finally:
+        jax.profiler.stop_trace()
+    host = _host_events(tmp_path)
+    tick, chunk, comp = (host["serve.tick"], host["serve.prefill_chunk"],
+                         host["compile"])
+    assert dict(chunk.stats) == {"uid": 3, "start": 256, "n": 128}
+    assert dict(comp.stats) == {"fun_name": "jit_step", "seconds": 0.5}
+    assert tick.start_ns <= chunk.start_ns
+    assert chunk.start_ns + chunk.duration_ns <= comp.start_ns
+    assert comp.start_ns + comp.duration_ns <= tick.start_ns + tick.duration_ns
+    assert [e["name"] for e in rec.events] == [
+        "serve.prefill_chunk", "compile", "serve.tick"]
+    assert rec.events[0]["args"] == {"uid": 3, "start": 256, "n": 128}
+
+
+def test_importing_obs_does_not_import_jax():
+    """The recorder imports ``jax`` on its first span, not on import."""
+    import subprocess
+    import sys
+
+    code = "import sys, repro.obs; sys.exit('jax' in sys.modules)"
+    env = dict(os.environ, PYTHONPATH=SRC)
+    assert subprocess.run([sys.executable, "-c", code], env=env).returncode == 0
+
+
+def test_compile_instants_on_the_installed_recorder_only():
+    """Jitting a new shape under an installed recorder records exactly one
+    ``compile`` instant naming the function; under the NullRecorder the
+    same listener records nowhere."""
+    import jax
+    import numpy as np
+
+    def double_plus_one(x):
+        return x * 2 + 1
+
+    rec = TraceRecorder()
+    with use_recorder(rec):
+        np.asarray(jax.jit(double_plus_one)(np.zeros((3, 17), np.float32)))
+    compiles = [e for e in rec.events if e["name"] == "compile"]
+    assert len(compiles) == 1, compiles
+    assert compiles[0]["ph"] == "i"
+    assert compiles[0]["args"]["fun_name"] == "jit(double_plus_one)"
+    assert compiles[0]["args"]["seconds"] >= 0
+    assert get_recorder() is NULL_RECORDER
+    n = len(rec.events)
+    np.asarray(jax.jit(double_plus_one)(np.zeros((5, 19), np.float32)))
+    assert len(rec.events) == n
 
 
 # ---------------------------------------------------------------------------

@@ -400,6 +400,70 @@ def test_scheduler_preempts_and_resumes_under_pressure():
     assert eng.pool.num_free == eng.pool.num_blocks - 1
 
 
+def _span_parents(rec):
+    """{(name, t): parent name} of a recorder's sync spans: the innermost
+    span that encloses each one in time (None for a root)."""
+    spans = [(e["t"], e["t"] + e["dur"], e["name"]) for e in rec.events
+             if e["ph"] == "X"]
+    out = {}
+    for t0, t1, name in spans:
+        outer = [s for s in spans if s[0] < t0 and t1 < s[1]]
+        out[(name, t0)] = max(outer)[2] if outer else None
+    return out
+
+
+def test_scheduler_tick_spans_nest_by_phase():
+    """One tick that runs a chunk, a final chunk and a decode records
+    ``serve.tick`` around every phase, the chunks and the first token
+    under ``serve.admit``, the decode step with its lane count; a tick that
+    preempts records ``serve.kv.evict`` under ``serve.grow``."""
+    import itertools
+
+    from repro.obs import TraceRecorder
+
+    rec = TraceRecorder(clock=itertools.count().__next__)
+    eng = _fake_engine(num_blocks=5, block_size=8, max_batch=2, capacity=32)
+    sched = Scheduler(SchedulerConfig(max_batch=2, prefill_chunk=8),
+                      clock=lambda: 0.0, trace=rec)
+    eng.scheduler = sched
+    sched.submit(_FakeReq(0, n_prompt=12, max_new=6))
+    sched.tick(eng)
+    parents = _span_parents(rec)
+    got = sorted((name, parent) for (name, _), parent in parents.items())
+    assert got == sorted([
+        ("serve.tick", None), ("serve.expire", "serve.tick"),
+        ("serve.admit", "serve.tick"),
+        ("serve.prefill_chunk", "serve.admit"),
+        ("serve.prefill_chunk", "serve.admit"),
+        ("serve.first_token", "serve.admit"),
+        ("serve.grow", "serve.tick"), ("serve.decode_step", "serve.tick"),
+        ("serve.emit", "serve.tick"),
+    ])
+    spans = [e for e in rec.events if e["ph"] == "X"]
+    chunks = [e["args"] for e in spans if e["name"] == "serve.prefill_chunk"]
+    assert chunks == [{"uid": 0, "start": 0, "n": 8},
+                      {"uid": 0, "start": 8, "n": 4}]
+    (step,) = [e for e in spans if e["name"] == "serve.decode_step"]
+    assert step["args"] == {"n_lanes": 1}
+    order = [e["name"] for e in sorted(spans, key=lambda e: e["t"])
+             if parents[(e["name"], e["t"])] == "serve.tick"]
+    assert order == ["serve.expire", "serve.admit", "serve.grow",
+                     "serve.decode_step", "serve.emit"]
+
+    # A second request fills the pool; growing the first past its third
+    # block evicts the newest holder.
+    sched.submit(_FakeReq(1, n_prompt=12, max_new=20))
+    for _ in range(40):
+        rec.events.clear()
+        sched.tick(eng)
+        if eng.evicted_uids:
+            break
+    assert eng.evicted_uids
+    parents = _span_parents(rec)
+    evicts = [p for (name, _), p in parents.items() if name == "serve.kv.evict"]
+    assert evicts == ["serve.grow"]
+
+
 # ---------------------------------------------------------------------------
 # Engine-path parity + end-to-end (ISSUE 5 acceptance)
 # ---------------------------------------------------------------------------
