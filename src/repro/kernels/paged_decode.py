@@ -37,8 +37,13 @@ block, unnormalised partials, the same cross-split LSE merge
   tokens wants another layout than the kernel's, so it would copy the
   whole pool each layer.  Token rows are what the insert scatter writes
   (``models.attention.paged_insert``) and they need no relayout.  A grid
-  step reads ``hb`` adjacent KV heads (``heads_per_step``), the fewest
-  whose lanes fill whole 128-lane tiles, and loops over them in VMEM.
+  step reads ``hb`` adjacent KV heads (``heads_per_step``): the most whose
+  double-buffered blocks fit ``VMEM_BLOCK_BUDGET``, which is every head at
+  decode, so the K/V block is the whole pool row.  A grid step costs a
+  fixed pipeline overhead (about 0.25 µs on a v5e) whatever it moves, and
+  one head's block of one pool block moves 32 KB; reading them all cuts
+  the grid 18–20×.  The step loops over its lane tiles in VMEM
+  (``tiles_unrolled`` of them unrolled an iteration).
 
 * **GQA head-packing + small-q_len banding** are shared verbatim with
   kernels/decode.py: rows pack ``q_per_kv × q_len`` queries per KV head,
@@ -53,6 +58,7 @@ Validated against gathered-contiguous oracles in tests/test_paged.py
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -71,14 +77,77 @@ def token_major(pool: jnp.ndarray) -> jnp.ndarray:
     return jnp.swapaxes(pool, -3, -2).reshape(*lead, p, bs, hkv * d)
 
 
-def heads_per_step(hkv: int, *widths: int) -> int:
-    """KV heads one grid step reads: the fewest (dividing ``hkv``) whose
-    lanes fill whole 128-lane tiles at every width, else all of them (a
-    block as wide as the array is always legal)."""
+# VMEM that one grid step's pipelined blocks (q, k, v, o, m, l), double
+# buffered and padded to the (sublane, 128-lane) tiling, may take.  The
+# kernel's scoped limit leaves as much again for the body's f32 temporaries.
+VMEM_BLOCK_BUDGET = 16 * 2**20
+VMEM_LIMIT = 2 * VMEM_BLOCK_BUDGET
+# Query rows the kernel's unrolled head loop may hold: one lane tile of a
+# 256-row chunk, whose body compiles for a v5e in about a second.  Unrolled
+# over all 18 tiles, minicpm-2b's chunk kernel took 30 s to compile.
+UNROLL_ROWS = 256
+
+
+def vmem_tile_bytes(shape: tuple[int, ...], dtype) -> int:
+    """Bytes a VMEM block of ``shape`` occupies: the last dim padded to 128
+    lanes, the second-last to the dtype's sublane count (8 rows of 32 bits,
+    so 16 of bf16)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    *lead, rows, lanes = shape
+    tiles = math.prod(lead) * -(-rows // sublanes) * -(-lanes // 128)
+    return tiles * sublanes * 128 * itemsize
+
+
+def step_vmem_bytes(hb: int, rows: int, d_score: int, d: int,
+                    block_size: int, q_dtype, kv_dtype) -> int:
+    """Double-buffered VMEM of one grid step that reads ``hb`` KV heads."""
+    f32 = jnp.float32
+    blocks = (
+        vmem_tile_bytes((hb, rows, d_score), q_dtype)
+        + vmem_tile_bytes((block_size, hb * d_score), kv_dtype)
+        + vmem_tile_bytes((block_size, hb * d), kv_dtype)
+        + vmem_tile_bytes((hb, rows, d), f32)
+        + 2 * vmem_tile_bytes((hb, 1, rows), f32)
+    )
+    return 2 * blocks
+
+
+def heads_per_tile(hkv: int, d_score: int, d: int) -> int:
+    """The fewest of ``hkv`` heads (a divisor) whose lanes fill whole
+    128-lane tiles at both widths, else all of them."""
     for hb in range(1, hkv + 1):
-        if hkv % hb == 0 and all(hb * w % 128 == 0 for w in widths):
+        if hkv % hb == 0 and hb * d_score % 128 == 0 and hb * d % 128 == 0:
             return hb
     return hkv
+
+
+def tiles_unrolled(n_tiles: int, rows: int) -> int:
+    """Lane tiles one iteration of the kernel's head loop unrolls: the most
+    (dividing ``n_tiles``) whose heads hold at most ``UNROLL_ROWS`` query
+    rows between them, so all of them at decode (rows 8) and one at a
+    256-row chunk."""
+    return max(
+        (u for u in range(1, n_tiles + 1)
+         if n_tiles % u == 0 and u * rows <= UNROLL_ROWS),
+        default=1,
+    )
+
+
+def heads_per_step(hkv: int, rows: int, d_score: int, d: int,
+                   block_size: int, q_dtype, kv_dtype) -> int:
+    """KV heads one grid step reads: the most whose blocks fit
+    ``VMEM_BLOCK_BUDGET``.  A grid step pays a fixed pipeline cost whatever
+    it moves, so fewer, wider steps are cheaper.  Candidates divide ``hkv``
+    and are legal K/V blocks: all heads (the whole pool row) or a whole
+    number of 128-lane tiles at both widths.  If none fits, the narrowest."""
+    per_tile = heads_per_tile(hkv, d_score, d)
+    legal = [hb for hb in range(hkv, 0, -per_tile) if hkv % hb == 0]
+    for hb in legal:
+        if step_vmem_bytes(hb, rows, d_score, d, block_size, q_dtype,
+                           kv_dtype) <= VMEM_BLOCK_BUDGET:
+            return hb
+    return legal[-1]
 
 
 def _paged_decode_kernel(
@@ -118,11 +187,9 @@ def _paged_decode_kernel(
             jnp.int32, (rows, block_size), 0
         ) % q_len
         mask = col < length - (q_len - 1 - row_tok)
-        for i in range(hb):  # static: the step's KV heads, lane slices
-            q = q_ref[i].astype(jnp.float32)  # (rows, d_score)
-            k = k_ref[:, i * d_score:(i + 1) * d_score].astype(jnp.float32)
-            v = v_ref[:, i * d:(i + 1) * d].astype(jnp.float32)
 
+        def head(i, k, v):  # step head i; k, v its lane slices, f32
+            q = q_ref[i].astype(jnp.float32)  # (rows, d_score)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -137,6 +204,29 @@ def _paged_decode_kernel(
             )
             m_ref[i] = m.reshape(1, rows)
             l_ref[i] = p.sum(axis=1, keepdims=True).reshape(1, rows)
+
+        # Loop over lane tiles of `per_tile` heads, `unroll` tiles an
+        # iteration (static lane slices within a tile).  Unrolled, the heads'
+        # small matmuls overlap; rolled, the code and its compile time stay a
+        # few tiles long however many heads the step reads.
+        per_tile = heads_per_tile(hb, d_score, d)
+        n_tiles = hb // per_tile
+        unroll = tiles_unrolled(n_tiles, rows)
+        wk, wv = per_tile * d_score, per_tile * d
+
+        def tiles(t, carry):
+            for u in range(unroll):
+                tile = t * unroll + u
+                k_t = k_ref[:, pl.ds(pl.multiple_of(tile * wk, wk), wk)]
+                v_t = v_ref[:, pl.ds(pl.multiple_of(tile * wv, wv), wv)]
+                for i in range(per_tile):
+                    k = k_t[:, i * d_score:(i + 1) * d_score]
+                    v = v_t[:, i * d:(i + 1) * d]
+                    head(tile * per_tile + i, k.astype(jnp.float32),
+                         v.astype(jnp.float32))
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles // unroll, tiles, 0)
 
     @pl.when(jnp.logical_not(live))
     def _dead():
@@ -175,7 +265,8 @@ def paged_decode_kernel_call(
     b, hkv, rows, d_score = q.shape
     block_size = v_pool.shape[2]
     d = v_pool.shape[3] // hkv
-    hb = heads_per_step(hkv, d_score, d)
+    hb = heads_per_step(hkv, rows, d_score, d, block_size, q.dtype,
+                        v_pool.dtype)
     max_blocks = block_tables.shape[1]
 
     def q_index(bi, h, j, lens, bt, layer):
@@ -221,6 +312,7 @@ def paged_decode_kernel_call(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
         name="paged_decode_splitk",

@@ -40,14 +40,20 @@ def _gather(pool, bt, hkv):
 # ---------------------------------------------------------------------------
 
 
+# (hkv, d): one head under a tile, several 64-lane heads a tile, one tile
+# a head.  The kernel reads every KV head of a pool block in one grid step.
+HEAD_LAYOUTS = [(2, 32), (6, 64), (3, 128)]
+
+
+@pytest.mark.parametrize("hkv,d", HEAD_LAYOUTS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("q_per_kv", [1, 4])
-def test_paged_kernel_matches_gathered_oracle(dtype, q_per_kv):
+def test_paged_kernel_matches_gathered_oracle(dtype, q_per_kv, hkv, d):
     """Ragged lengths (incl. block-boundary crossings and single-token) over
     shuffled physical blocks equal the contiguous decode oracle."""
     from repro.kernels import ops, ref
 
-    b, hkv, d, bs, mb = 4, 2, 32, 8, 4
+    b, bs, mb = 4, 8, 4
     k_pool, v_pool, bt, kq = _random_pool_case(
         jax.random.PRNGKey(0), b, hkv, d, bs, mb, dtype
     )
@@ -69,37 +75,36 @@ def test_paged_kernel_matches_gathered_oracle(dtype, q_per_kv):
     )
 
 
-def test_paged_kernel_banded_window():
+# (hkv, d, q_len, table width): 2 × 136 packed rows leave the kernel's head
+# loop rolled, one lane tile an iteration (``tiles_unrolled``).
+@pytest.mark.parametrize("hkv,d,ql,mb", [(2, 32, 4, 4), (6, 64, 136, 20)])
+def test_paged_kernel_banded_window(hkv, d, ql, mb):
     """q_len > 1 (chunked prefill): row i sees positions
     < length − (q_len − 1 − i), matching the contiguous kernel's band."""
     from repro.kernels import ops
+    from repro.core.flash_reference import reference_attention
 
-    b, hkv, d, bs, mb, ql = 2, 2, 32, 8, 4, 4
+    b, bs = 2, 8
     k_pool, v_pool, bt, kq = _random_pool_case(
         jax.random.PRNGKey(1), b, hkv, d, bs, mb
     )
-    q = jax.random.normal(kq, (b, 4, ql, d), jnp.float32)
-    lengths = jnp.asarray([17, 9], jnp.int32)
+    q = jax.random.normal(kq, (b, 2 * hkv, ql, d), jnp.float32)
+    lengths = jnp.asarray([ql + 13, ql + 5], jnp.int32)
     out = ops.paged_decode_attention(
         q, k_pool, v_pool, block_tables=bt, lengths=lengths
     )
-    from repro.core.flash_reference import reference_attention
-
+    # Each (request, query token) is one oracle batch row with its own band.
     k_c, v_c = _gather(k_pool, bt, hkv), _gather(v_pool, bt, hkv)
-    for bi in range(b):
-        for i in range(ql):
-            mask = (
-                jnp.arange(mb * bs)[None, :]
-                < int(lengths[bi]) - (ql - 1 - i)
-            )
-            want = reference_attention(
-                q[bi : bi + 1, :, i : i + 1], k_c[bi : bi + 1],
-                v_c[bi : bi + 1], kv_mask=mask,
-            )
-            np.testing.assert_allclose(
-                np.asarray(out[bi : bi + 1, :, i : i + 1]), np.asarray(want),
-                rtol=2e-5, atol=2e-5,
-            )
+    band = lengths[:, None] - (ql - 1 - jnp.arange(ql))[None, :]  # (b, ql)
+    mask = jnp.arange(mb * bs)[None, None, :] < band[:, :, None]
+    want = reference_attention(
+        q.transpose(0, 2, 1, 3).reshape(b * ql, 2 * hkv, 1, d),
+        jnp.repeat(k_c, ql, axis=0), jnp.repeat(v_c, ql, axis=0),
+        kv_mask=mask.reshape(b * ql, mb * bs),
+    ).reshape(b, ql, 2 * hkv, d).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5
+    )
 
 
 def test_paged_kernel_window_overhanging_capacity():

@@ -8,8 +8,11 @@ interpret mode cannot see.  Nothing runs.  The topology is built in a
 module fixture (never at import), so every test worker collects the same
 tests and only the worker that runs this file loads the TPU library.
 
-Layouts: minicpm-2b (36 × 64 MHA) and qwen2.5-32b (40:8 × 128 GQA).
+Layouts: minicpm-2b (36 × 64 MHA) and qwen2.5-32b (40:8 × 128 GQA); the
+paged kernel also at qwen1.5-4b's (20 × 128 MHA).
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -19,9 +22,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.core.distr_attention import DistrConfig
 from repro.kernels import ops
-from repro.kernels.paged_decode import heads_per_step
+from repro.kernels import paged_decode
 
 LAYOUTS = {"36x64": (36, 36, 64), "40:8x128": (40, 8, 128)}
+PAGED_LAYOUTS = {**LAYOUTS, "20x128": (20, 20, 128)}
 N = 512  # prefill / training sequence
 POOL_BLOCKS, BLOCK, TABLE = 129, 128, 16  # paged pool of the serving engine
 
@@ -118,12 +122,12 @@ def test_split_k_decode_compiles(one_chip, layout):
 
 
 @pytest.mark.parametrize("q_len", [1, 256])
-@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("layout", PAGED_LAYOUTS)
 def test_paged_decode_compiles(one_chip, layout, q_len):
     """Token decode (8 lanes) and one 256-token chunked-prefill window over
     the serving engine's (L, P, bs, Hkv·d) pool stack, read at a traced
-    layer."""
-    hq, hkv, d = LAYOUTS[layout]
+    layer: every KV head a grid step fits the kernel's VMEM limit."""
+    hq, hkv, d = PAGED_LAYOUTS[layout]
     b = 8 if q_len == 1 else 1
 
     def dec(q, k_pool, v_pool, tables, lengths, layer):
@@ -140,11 +144,53 @@ def test_paged_decode_compiles(one_chip, layout, q_len):
              f((b,), jnp.int32), f((), jnp.int32))
 
 
-def test_paged_heads_per_step_fill_lanes():
-    assert heads_per_step(36, 64, 64) == 2
-    assert heads_per_step(36, 32, 64) == 4  # fused K̂ at G* = 2
-    assert heads_per_step(8, 128, 128) == 1
-    assert heads_per_step(2, 32, 32) == 2  # narrower than a tile: whole row
+# (Hkv, d_score, d): minicpm-2b, qwen1.5-4b, qwen2.5-32b (GQA 40:8) and
+# minicpm-2b's fused-K̂ pool at G* = 2.
+HEAD_SHAPES = [(36, 64, 64), (20, 128, 128), (8, 128, 128), (36, 32, 64)]
+
+
+@pytest.mark.parametrize("hkv,d_score,d", HEAD_SHAPES)
+def test_paged_heads_per_step_from_shapes(hkv, d_score, d):
+    """A decode step (8 packed rows) reads every KV head of a pool block; a
+    256-token chunk (5 queries a KV head under 40:8 GQA) reads the most
+    heads whose double-buffered, lane-padded blocks fit the VMEM budget."""
+    bf16 = jnp.bfloat16
+    hps = functools.partial(
+        paged_decode.heads_per_step, hkv, d_score=d_score, d=d,
+        block_size=BLOCK, q_dtype=bf16, kv_dtype=bf16,
+    )
+    assert hps(rows=8) == hkv
+
+    rows = 256 * (5 if hkv == 8 else 1)
+    hb = hps(rows=rows)
+    fits = functools.partial(
+        paged_decode.step_vmem_bytes, rows=rows, d_score=d_score, d=d,
+        block_size=BLOCK, q_dtype=bf16, kv_dtype=bf16,
+    )
+    assert hkv % hb == 0 and hb * d_score % 128 == 0 and hb * d % 128 == 0
+    assert fits(hb) <= paged_decode.VMEM_BLOCK_BUDGET
+    wider = [h for h in range(hb + 1, hkv + 1) if hkv % h == 0
+             and h * d_score % 128 == 0 and h * d % 128 == 0]
+    assert all(fits(h) > paged_decode.VMEM_BLOCK_BUDGET for h in wider)
+
+
+def test_vmem_bytes_count_padded_tiles():
+    """A 64-lane head occupies 128 lanes; bf16 rows pad to 16 sublanes."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert paged_decode.vmem_tile_bytes((36, 8, 64), bf16) == 36 * 16 * 128 * 2
+    assert paged_decode.vmem_tile_bytes((36, 1, 8), f32) == 36 * 8 * 128 * 4
+    assert paged_decode.vmem_tile_bytes((128, 2304), bf16) == 128 * 2304 * 2
+    # minicpm-2b's 256-token chunk: all 36 heads would overflow the budget
+    assert paged_decode.step_vmem_bytes(36, 256, 64, 64, BLOCK, bf16, bf16) \
+        > paged_decode.VMEM_BLOCK_BUDGET
+    assert paged_decode.heads_per_step(36, 256, 64, 64, BLOCK, bf16, bf16) == 18
+
+
+@pytest.mark.parametrize("n_tiles,rows,unroll", [
+    (18, 8, 18), (20, 8, 20), (18, 256, 1), (4, 1280, 1), (18, 24, 9),
+])
+def test_paged_head_loop_unroll_from_rows(n_tiles, rows, unroll):
+    assert paged_decode.tiles_unrolled(n_tiles, rows) == unroll
 
 
 def test_ring_flash_forward_compiles_on_four_chips(topo):
