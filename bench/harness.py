@@ -17,7 +17,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from bench import reference, traffic, weights, work
+from bench import manifest, reference, traffic, weights, work
 
 TERMINAL = ("done", "rejected", "expired", "cancelled", "failed")
 
@@ -48,17 +48,6 @@ def program_config(conf: dict):
     return cfg.replace(**{k: _lookup(conf, v) for k, v in prog["applied"].items()})
 
 
-def reference_spec(conf: dict) -> dict:
-    return {
-        "n_heads": conf["num_attention_heads"],
-        "n_kv_heads": conf["num_key_value_heads"],
-        "head_dim": conf["derived"]["head_dim"],
-        "rope_theta": float(conf["rope_theta"]),
-        "norm_eps": float(conf["rms_norm_eps"]),
-        "vocab": conf["vocab_size"],
-    }
-
-
 def make_weights(cfg, seed: int):
     """The benchmark's seeded weights in the layout the program serves."""
     import functools
@@ -76,19 +65,21 @@ def make_weights(cfg, seed: int):
 
 def pool_blocks(cfg, conf: dict, max_len: int) -> tuple[int, int]:
     """(num_blocks, block_size): the configuration's pool bytes in blocks of
-    the size the program's tuner picks, plus the reserved garbage block."""
+    the size the program's tuner picks, plus the reserved garbage block.
+    The program's bytes a token have to be what the configuration's
+    architecture counts."""
     from repro.serve import paged
     from repro.tune.autotune import warm_paged_engine
 
     block = min(warm_paged_engine(cfg, max_len).get("paged_decode", 128), max_len)
-    shape = work.Shape.from_config(conf)
+    want = manifest.architecture(conf).kv_bytes_per_token(conf)
     per_token = sum(
         s.size * s.dtype.itemsize
         for s in paged.pool_struct(cfg, 1, 1).values()
     )
-    if per_token != shape.kv_bytes_per_token:
+    if per_token != want:
         raise SystemExit(f"program pools {per_token} B per token, "
-                         f"configuration says {shape.kv_bytes_per_token}")
+                         f"configuration says {want}")
     blocks = conf["serving"]["pool_bytes"] // (block * per_token)
     return blocks + 1, block
 
@@ -322,7 +313,9 @@ def warm_up(eng, mix: dict, vocab: int, seed: int) -> None:
 
 class CallLog:
     """Wraps the engine's two model-step primitives on this instance to log
-    the work of each call and to open a host span around it."""
+    each call and to open a host span around it.  A call is
+    ``{"kind": "decode", "lengths": [live keys of each occupied lane]}`` or
+    ``{"kind": "chunk", "start": first row, "n": rows, "final": bool}``."""
 
     def __init__(self, eng):
         import jax
@@ -333,33 +326,34 @@ class CallLog:
 
         def decode_tick(running):
             if self.on:
-                self.calls.append(("decode", [e.length + 1 for e in running.values()]))
+                self.calls.append({"kind": "decode",
+                                   "lengths": [e.length + 1 for e in running.values()]})
             with jax.profiler.TraceAnnotation("bench.decode_step"):
                 return decode(running)
 
         def prefill_chunk_run(entry, n):
             if self.on:
                 final = entry.prompt_done + n == len(entry.req.prompt)
-                self.calls.append(("chunk", entry.prompt_done, n, final))
+                self.calls.append({"kind": "chunk", "start": entry.prompt_done,
+                                   "n": n, "final": final})
             with jax.profiler.TraceAnnotation("bench.prefill_chunk"):
                 return chunk(entry, n)
 
         eng.decode_tick = decode_tick
         eng.prefill_chunk_run = prefill_chunk_run
 
-    def work(self, shape: work.Shape) -> dict:
-        out = {k: work.Work() for k in ("decode_step", "chunk_step",
-                                       "decode_kernel", "chunk_kernel")}
+    def work(self, conf: dict) -> dict:
+        """Calls of each kind, and one ``Work`` summed over the logged calls
+        for every entry of the configuration's architecture's ``WORK``."""
+        table = manifest.architecture(conf).WORK
+        out = {k: work.Work() for k in table}
         counts = {"decode": 0, "chunk": 0}
         for call in self.calls:
-            counts[call[0]] += 1
-            if call[0] == "decode":
-                out["decode_step"] += work.decode_step(shape, call[1])
-                out["decode_kernel"] += work.decode_kernel(shape, call[1])
-            else:
-                _, start, n, final = call
-                out["chunk_step"] += work.chunk_step(shape, start, n, final)
-                out["chunk_kernel"] += work.chunk_kernel(shape, start, n)
+            counts[call["kind"]] += 1
+            for key, count in table.items():
+                w = count(conf, call)
+                if w is not None:
+                    out[key] += w
         return {"counts": counts, **{k: vars(v) for k, v in out.items()}}
 
 
@@ -390,11 +384,13 @@ def compare(params, conf: dict, finished: list, mix: dict, controls=()) -> dict:
     """Readings over the sampled requests, for the served tokens and for
     each control in ``controls``: ``logit_gap``, the widest gap of a
     token's reference logit below the reference's best, and
-    ``logit_gap_mean``, the mean gap over every compared token."""
-    spec = reference_spec(conf)
+    ``logit_gap_mean``, the mean gap over every compared token.  The
+    reference is the configuration's architecture's."""
+    arch = manifest.architecture(conf)
+    spec = arch.spec(conf)
     widest, sums, tokens = {}, {}, 0
     for prompt, generated in finished:
-        out = reference.check_request(params, spec, prompt, generated,
+        out = reference.check_request(arch, params, spec, prompt, generated,
                                       length=reference_length(mix),
                                       n_rows=mix["output"]["max"], controls=controls)
         tokens += out.pop("tokens")

@@ -29,10 +29,27 @@ def step_share(record: dict, work_key: str, step: str, time_key: str):
     steps = (record.get("trace") or {}).get("steps", {})
     if step not in steps or steps[step][time_key] <= 0:
         return None
+    return 100.0 * least_s(record, work_key) / steps[step][time_key]
+
+
+def op_share(record: dict, work_key: str, *ops: str):
+    """Least time for the logged work under ``work_key`` (an entry of the
+    architecture's ``WORK``) at the chip's peaks over the device self time
+    of ``ops`` (each ``"<step kind>/<op>"``) in the trace's ``op_s``, in
+    percent; None when the trace has no time for them."""
+    op_s = (record.get("trace") or {}).get("op_s", {})
+    seconds = sum(op_s.get(op, 0.0) for op in ops)
+    if seconds <= 0:
+        return None
+    return 100.0 * least_s(record, work_key) / seconds
+
+
+def least_s(record: dict, work_key: str) -> float:
+    """Least seconds the logged work under ``work_key`` needs at the chip's
+    peaks: the larger of the FLOP and the HBM-byte term."""
     w = record["work"][work_key]
     peaks = record["peaks"]
-    least = max(w["flops"] / peaks["flops_bf16"], w["bytes"] / peaks["hbm_bytes_per_s"])
-    return 100.0 * least / steps[step][time_key]
+    return max(w["flops"] / peaks["flops_bf16"], w["bytes"] / peaks["hbm_bytes_per_s"])
 
 
 def idle_share(record: dict):

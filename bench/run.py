@@ -10,7 +10,8 @@ weights from the seed on the device, build the paged engine, warm every
 program the window runs, serve the mix's pre-roll, drive the window for
 ``--seconds``, read the peak memory, free the engine, and compare a sample
 of the requests finished in the window with the plain reference
-(``bench/reference.py``).  With ``--trace 1`` the window
+(``bench/reference.py``, with the configuration's architecture module
+``bench/architectures/<name>.py``).  With ``--trace 1`` the window
 runs under the profiler and the line carries the per-layer metrics instead
 of the end-to-end ones.
 
@@ -34,7 +35,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "src"))
 
-from bench import harness, manifest, trace_reduce, traffic, work  # noqa: E402
+from bench import harness, manifest, trace_reduce, traffic  # noqa: E402
 
 OUT = ROOT / ".bench_out"
 
@@ -97,7 +98,6 @@ def run_cell(man: dict, cell: dict, seed: int, seconds: float, trace: bool,
     conf = conf or manifest.read_config(man, cell["config"])
     mix = mix or traffic.load_mix(cell["traffic"])
     cfg = harness.program_config(conf)
-    shape = work.Shape.from_config(conf)
 
     params = harness.make_weights(cfg, seed)
     jax.block_until_ready(params)
@@ -142,7 +142,7 @@ def run_cell(man: dict, cell: dict, seed: int, seconds: float, trace: bool,
         reduced = trace_reduce.reduce(events, step_module=mix["trace"]["step_module"],
                                       kernel=mix["trace"]["kernel"],
                                       chunk_rows=mix["engine"]["prefill_chunk"])
-        record.update(trace=reduced, work=calls.work(shape), peaks=peaks)
+        record.update(trace=reduced, work=calls.work(conf), peaks=peaks)
         if reduced:
             dev.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
             breakdown = {"device_ops": reduced["device_ops"],

@@ -83,6 +83,32 @@ def test_the_int8_control_is_not_correct(tiny):
     assert not int8["correct"], out
 
 
+GOLDEN = json.loads((DATA / "golden_compare.json").read_text())
+
+
+@pytest.mark.parametrize("seed", sorted(GOLDEN))
+def test_compare_reads_as_the_llama_only_harness_did(tiny, seed):
+    """The reference through the configuration's architecture module gives
+    the readings the harness gave when its reference was llama's alone
+    (``data/golden_compare.json``): served tokens and both controls, on
+    hand-made requests over the tiny file's weights. Counts are exact; gaps
+    agree to 1e-6 relative, far inside any control's distance, so that the
+    CPU's code generation on another host or version does not fail it."""
+    from bench import harness, reference
+
+    conf, mix = tiny
+    params = harness.make_weights(harness.program_config(conf), int(seed))
+    finished = GOLDEN[seed]["finished"]
+    got = harness.compare(params, conf, finished, mix, controls=reference.QUANTS)
+    want = GOLDEN[seed]["readings"]
+    assert (got["requests"], got["tokens"]) == (want["requests"], want["tokens"])
+    groups = {"served": got["served"], **got["controls"]}
+    assert groups.keys() == {"served", *want["controls"]}
+    for name, gaps in groups.items():
+        expected = want["served"] if name == "served" else want["controls"][name]
+        assert gaps == pytest.approx(expected, rel=1e-6), name
+
+
 def test_the_verdict_needs_every_reading_under_its_limit():
     from bench import harness
 

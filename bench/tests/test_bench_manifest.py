@@ -1,10 +1,16 @@
 """BENCHMARK.json against the benchmark's contract, and each name's files."""
 import copy
+import importlib.util
 import json
+import sys
+from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
 from bench import manifest, traffic
+
+DATA = Path(__file__).resolve().parent / "data"
 
 MAN = manifest.load()
 CELLS = [c["name"] for c in MAN["workloads"]]
@@ -113,6 +119,129 @@ def test_a_new_cell_needs_only_new_entries(tmp_path):
     assert "decode_step_mfu.itl" in names
     for n in names:
         assert manifest.metric_module(n).NAME == n
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MAN["configs"]])
+def test_every_configuration_names_an_architecture_module(config):
+    conf = manifest.read_config(MAN, config)
+    assert (manifest.BENCH / "architectures" / f"{conf['architecture']}.py").is_file()
+    arch = manifest.architecture(conf)
+    for name in ("spec", "logits", "kv_bytes_per_token"):
+        assert callable(getattr(arch, name)), name
+    assert arch.WORK and all(callable(f) for f in arch.WORK.values())
+    assert arch.kv_bytes_per_token(conf) > 0 and arch.spec(conf)
+
+
+def test_an_architecture_has_no_default():
+    conf = manifest.read_config(MAN, "minicpm-2b")
+    del conf["architecture"]
+    with pytest.raises(KeyError):
+        manifest.architecture(conf)
+    with pytest.raises(ValueError):
+        manifest.architecture(dict(conf, architecture="../llama"))
+
+
+TOY = {"architecture": "toy_hybrid", "vocab_size": 64, "hidden_size": 32,
+       "num_key_value_heads": 4, "head_dim": 32,
+       "layer_types": ["state", "attention", "state", "state", "attention"],
+       "serving": {"chips": 1, "pool_bytes": 1024 * 128 * 6}}
+
+
+@pytest.fixture
+def toy(monkeypatch):
+    """``bench/tests/data/toy_hybrid.py`` found by name, as it would be
+    under ``bench/architectures/``: the data directory joins the package's
+    path for the test."""
+    from bench import architectures
+
+    name = "bench.architectures.toy_hybrid"
+    monkeypatch.setattr(architectures, "__path__", [*architectures.__path__, str(DATA)])
+    monkeypatch.delitem(sys.modules, name, raising=False)
+    yield copy.deepcopy(TOY)
+    sys.modules.pop(name, None)
+
+
+def test_a_new_architecture_needs_only_new_files(toy, tmp_path):
+    """A configuration of another architecture, with a module of its own
+    (toy reference, pool bytes of its attention layers only, a per-lane
+    state in its step counts and a kernel of its own), goes through the
+    manifest, the reference comparison, the pool check, the call log and a
+    metric over ``op_s`` with no harness file changed."""
+    import jax
+    import numpy as np
+
+    from bench import harness, reference
+
+    conf_file = tmp_path / "toy.json"
+    conf_file.write_text(json.dumps(toy))
+    man = copy.deepcopy(MAN)
+    man["configs"].append({"name": "toy", "source": "x", "file": str(conf_file),
+                           "reduced": [], "why": "x"})
+    man["workloads"].append({"name": "toy.chat", "config": "toy", "traffic": "chat",
+                             "chips": 1, "why": "x"})
+    conf = manifest.read_config(man, manifest.workload(man, "toy.chat")["config"])
+    arch = manifest.architecture(conf)
+    assert arch.__name__ == "bench.architectures.toy_hybrid"
+
+    # compare: greedy tokens of the toy reference read no gap, an altered
+    # one does.
+    mix = traffic.load_mix("chat")
+    mix = dict(mix, prompt=dict(mix["prompt"], max=40), output=dict(mix["output"], max=8))
+    table = jax.random.normal(jax.random.PRNGKey(0), (64, 32), "float32")
+    params = {"embed": {"table": table}}
+    spec, length = arch.spec(conf), harness.reference_length(mix)
+    prompt = list(np.random.default_rng(1).integers(1, 64, 20))
+    served = []
+    for _ in range(6):
+        tokens = prompt + served
+        out = reference.logits(arch, params, spec, tokens, [len(tokens) - 1],
+                               length=length, n_rows=8)
+        served.append(int(out[0].argmax()))
+    got = harness.compare(params, conf, [(prompt, served)], mix, controls=reference.QUANTS)
+    assert got["tokens"] == 6 and got["served"]["logit_gap"] < 1e-5
+    assert set(got["controls"]) == set(reference.QUANTS)
+    altered = served[:3] + [(served[3] + 1) % 64] + served[4:]
+    assert harness.compare(params, conf, [(prompt, altered)], mix)["served"]["logit_gap"] > 1e-3
+
+    # the pool check: the program's pool of two attention layers is what
+    # the toy counts, and three would not be.
+    import repro.configs as configs
+
+    cfg = configs.get_config("minicpm-2b", reduced=True).replace(vocab=64, n_layers=2)
+    blocks, block = harness.pool_blocks(cfg, conf, 128)
+    assert blocks == 1024 * 128 * 6 // (block * 1024) + 1
+    with pytest.raises(SystemExit, match="configuration says 1536"):
+        harness.pool_blocks(cfg, dict(conf, layer_types=["attention"] * 3), 128)
+
+    # the call log counts every entry of the toy's WORK, its state term
+    # once a lane.
+    log = harness.CallLog(SimpleNamespace(decode_tick=None, prefill_chunk_run=None))
+    log.calls = [{"kind": "decode", "lengths": [10, 30]},
+                 {"kind": "chunk", "start": 0, "n": 16, "final": True},
+                 {"kind": "decode", "lengths": [11, 31, 5]}]
+    work = log.work(conf)
+    assert set(work) == {"counts", "decode_step", "chunk_step", "scan_kernel"}
+    assert work["counts"] == {"decode": 2, "chunk": 1}
+    state = arch.STATE_BYTES
+    assert work["scan_kernel"] == {"flops": 2 * state * (2 + 16 + 3),
+                                   "bytes": 2 * state * (2 + 1 + 3)}
+    weights, kv = 64 * 32 * 2, arch.kv_bytes_per_token(conf)
+    assert work["decode_step"]["bytes"] == (2 * weights + (40 + 47) * kv
+                                            + 5 * 2 * state + 5 * 64 * 4)
+
+    # a metric file over op_s and a WORK key.
+    path = DATA / "toy_scan_roofline.py"
+    metric = importlib.util.module_from_spec(
+        importlib.util.spec_from_file_location("toy_scan_roofline", path))
+    metric.__spec__.loader.exec_module(metric)
+    peaks = {"flops_bf16": 1e12, "hbm_bytes_per_s": 1e9}
+    record = {"work": work, "peaks": peaks,
+              "trace": {"op_s": {"decode/toy_scan": 2e-5, "chunk/toy_scan": 1e-5,
+                                 "decode/fusion": 1.0}}}
+    least = max(work["scan_kernel"]["flops"] / 1e12, work["scan_kernel"]["bytes"] / 1e9)
+    assert metric.compute(record) == pytest.approx(100 * least / 3e-5)
+    assert metric.compute(dict(record, trace={"op_s": {"decode/fusion": 1.0}})) is None
+    assert metric.compute({}) is None
 
 
 def test_engine_arguments_come_from_the_mix():

@@ -94,13 +94,35 @@ def test_compiles_are_counted_inside_the_window():
     assert ps.compiles_in(recorded, 0.0, 9.0) == 3
 
 
+def reads_as_before(path, reduced):
+    """``trace_reduce.reduce`` of ``path`` is the stored reduction
+    ``reduced`` plus ``op_s``: every op's self time, of which the ten
+    largest are ``device_ops`` unchanged, and which together are the busy
+    time of the one device."""
+    out = json.loads(json.dumps(tr.reduce(tr.read(path), step_module="jit_paged_step",
+                                          kernel="paged_decode_splitk", chunk_rows=32)))
+    op_s = out.pop("op_s")
+    assert out == json.loads((DATA / reduced).read_text())
+    for name, seconds in out["device_ops"]:
+        assert op_s[name] == seconds
+    assert len(op_s) > len(out["device_ops"]) == 10
+    assert min(s for _, s in out["device_ops"]) >= max(
+        s for k, s in op_s.items() if k not in dict(out["device_ops"]))
+    assert sum(op_s.values()) == pytest.approx(out["busy_s"])
+
+
 def test_trace_reduce_reads_the_old_fixture_as_before():
     """The reduction of the v5e fixture of three ticks, as it read when
     the program had no spans of its own."""
-    out = tr.reduce(tr.read(DATA / "paged_steps.xplane.pb"), step_module="jit_paged_step",
-                    kernel="paged_decode_splitk", chunk_rows=32)
-    want = json.loads((DATA / "paged_steps.reduced.json").read_text())
-    assert json.loads(json.dumps(out)) == want
+    reads_as_before(DATA / "paged_steps.xplane.pb", "paged_steps.reduced.json")
+
+
+def test_trace_reduce_reads_the_spans_fixture_as_before(tmp_path):
+    """The reduction of the recorded program-spans fixture, as it read
+    before ``op_s`` was kept."""
+    path = tmp_path / "serve_spans.xplane.pb"
+    path.write_bytes(gzip.decompress(SPANS_FIXTURE.read_bytes()))
+    reads_as_before(path, "serve_spans.reduced.json")
 
 
 def test_recorded_program_spans(tmp_path):

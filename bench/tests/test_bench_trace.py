@@ -55,6 +55,20 @@ def test_device_ops_count_self_time():
     assert ops["chunk/paged_decode_splitk"] == pytest.approx(20e-9)
     assert ops["jit_argmax/copy"] == pytest.approx(5e-9)
     assert sum(ops.values()) == pytest.approx(45e-9)
+    assert out["op_s"] == ops
+
+
+def test_op_s_keeps_every_op_past_the_top():
+    """``device_ops`` is cut to ``top``; ``op_s`` keeps every op, so a
+    kernel's time is there whatever its rank."""
+    out = tr.reduce(synthetic(), step_module="jit_paged_step",
+                    kernel="paged_decode_splitk", chunk_rows=32, top=2)
+    assert [k for k, _ in out["device_ops"]] == ["chunk/paged_decode_splitk",
+                                                 "decode/paged_decode_splitk"]
+    assert set(out["op_s"]) == {"decode/while", "decode/fusion",
+                                "decode/paged_decode_splitk",
+                                "chunk/paged_decode_splitk", "jit_argmax/copy"}
+    assert out["op_s"]["decode/while"] == pytest.approx(6e-9)
 
 
 def test_idle_gaps_are_named_by_the_open_host_span():

@@ -17,9 +17,10 @@ Reads the ``.xplane.pb`` file with ``jax.profiler.ProfileData`` and keeps:
   whose kernel has fewer than ``chunk_rows`` query rows is a decode step,
   any other a prefill chunk.  Per class: executions, device seconds of the
   step, and device seconds of its kernel calls;
-- the device operations that took most time (self time, so an op that
-  encloses others, such as a loop, counts only its own part), named
-  ``<step class or module>/<op>``;
+- every device operation's self time (so an op that encloses others, such
+  as a loop, counts only its own part), named ``<step class or
+  module>/<op>``: all of them in ``op_s``, the ``top`` that took most time
+  in ``device_ops``;
 - the longest idle gaps of device 0, each named by the innermost harness
   span (``bench.*``) on the host that was open at the gap's midpoint.
 """
@@ -187,6 +188,7 @@ def reduce(events: dict, *, step_module: str, kernel: str, chunk_rows: int,
         "steps": {k: {"count": v[0], "device_s": v[1], "kernel_s": v[2]}
                   for k, v in steps.items()},
         "device_ops": [[k, v / 1e9] for k, v in ranked],
+        "op_s": {k: v / 1e9 for k, v in op_ns.items()},
         "idle_gaps": sorted(([k, v] for k, v in gaps.items()),
                             key=lambda kv: -kv[1])[:top],
     }
